@@ -1,7 +1,8 @@
 """Network tier under fan-in: latency vs connection count + shed behavior.
 
 One `FViewServer` (thread-hosted, same binary the CI server-smoke lane
-runs as a subprocess) and an asyncio load generator speaking raw
+runs as a subprocess on the CPU; on a TPU it must stay a thread of the
+process holding the chip) and an asyncio load generator speaking raw
 `net/wire.py` frames — no client-library batching, so what is measured
 is the server's own multiplexing: admission, the 2 ms coalescing
 window, ONE worker-thread flush per drain round, completion-order

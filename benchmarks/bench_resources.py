@@ -23,17 +23,21 @@ def _vmem(*shapes_dtypes) -> int:
 
 
 def run() -> None:
-    # select_project: (256,128) f32 in + out + 3 param rows + perm matrix
-    br, c = 256, 128
-    v = _vmem(((br, c), 4), ((br, c), 4), ((3, c), 4), ((br, br), 4))
+    # select_project: column-major (C=8, 256) f32 block in + out, 3
+    # (8, 1) param columns (one (8, 128) tile each), the triangular and
+    # permutation matrices, and the two 16-bit halves moved through them
+    br, c = 256, 8
+    v = _vmem(((c, br), 4), ((c, br), 4), ((3 * c, 128), 4),
+              ((br, br), 4), ((br, br), 4), ((2 * c, br), 4))
     row("resources", "select_project", 0, vmem_kb=v // 1024,
         vmem_pct=round(100 * v / VMEM_BYTES, 2),
-        flops_per_row=2 * c + 2 * br)     # predicate + perm-matmul row
+        flops_per_row=2 * c + 4 * c * br)  # predicate + two perm matmuls
 
-    # hash_group: block rows + bucket tables (B=1024, V=4)
-    b, vcols = 1024, 4
-    v = _vmem(((br, 1), 4), ((br, vcols), 4), ((b, 1), 4), ((b, 1), 4),
-              ((b, vcols), 4), ((b, vcols), 4), ((b, vcols), 4),
+    # hash_group: (1, 256) bucket/owns rows, (V=8, 256) values, the
+    # (1, B) count and (V, B) sum/min/max tables (B=1024), the one-hot
+    b, vcols = 1024, 8
+    v = _vmem(((8, br), 4), ((8, br), 4), ((vcols, br), 4), ((8, b), 4),
+              ((vcols, b), 4), ((vcols, b), 4), ((vcols, b), 4),
               ((b, br), 4))
     row("resources", "hash_group", 0, vmem_kb=v // 1024,
         vmem_pct=round(100 * v / VMEM_BYTES, 2),

@@ -38,7 +38,8 @@ def quick() -> bool:
 
 def _materialize(x) -> None:
     """Block on the timed closure's result: finalize lazy pipeline results,
-    wait for device arrays; plain python/numpy values pass through."""
+    wait for device arrays; values that are not arrays pass through. A
+    device error propagates: a failed run must never pass as a fast one."""
     if x is None:
         return
     if hasattr(x, "finalize"):
@@ -48,10 +49,7 @@ def _materialize(x) -> None:
         for e in x:
             _materialize(e)
         return
-    try:
-        jax.block_until_ready(x)
-    except Exception:
-        pass
+    jax.block_until_ready(x)
 
 
 def timeit(fn, *, repeat: int = 5, warmup: int = 2) -> float:
@@ -106,7 +104,7 @@ def rows_as_records() -> list[dict]:
 
 def bench_meta() -> dict:
     """Provenance stamp for --json output: git commit, jax version, device
-    platform, quick-mode flag. BENCH_*.json files carry it so the perf
+    platform, kind and count, quick-mode flag. BENCH_*.json files carry it so the perf
     trajectory is comparable PR over PR (and the CI regression guard can
     refuse to compare quick-mode against full-mode numbers)."""
     import subprocess
@@ -121,6 +119,8 @@ def bench_meta() -> dict:
         "git_commit": commit,
         "jax_version": jax.__version__,
         "platform": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": jax.device_count(),
         "quick": QUICK,
     }
 

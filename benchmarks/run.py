@@ -55,6 +55,7 @@ from benchmarks import (bench_chaos, bench_cluster_scaleout, bench_crypto,
                         bench_regex, bench_resources, bench_selection,
                         bench_tiering, common)
 from benchmarks.common import print_csv, write_json
+from repro import compile_cache
 
 ALL = {
     "rdma": bench_rdma.run,
@@ -87,6 +88,7 @@ def main() -> None:
                     help="smoke mode (CI): 1 warmup + 1 repeat, reduced "
                          "sizes — indicative timings, exact byte columns")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.quick:
         common.QUICK = True
     for name, fn in ALL.items():

@@ -180,6 +180,14 @@ def run_offloaded(mesh: Mesh, axis: str, schema: FTable, pipeline: tuple,
                                            for p in partials))
 
 
+# farlint: finalize-boundary (client merge: survivors are host data here)
+def _ctr_host(rows: np.ndarray, crypt) -> np.ndarray:
+    """The CTR cipher over host rows, keystream from position 0."""
+    u32 = jnp.asarray(rows.reshape(-1).view(np.uint32))
+    out = kref.ctr_crypt(u32, jnp.asarray(crypt.key, jnp.uint32), crypt.nonce)
+    return np.asarray(out).view(np.float32).reshape(rows.shape)
+
+
 def _merge(schema: FTable, pipeline: tuple,
            partials: list[PipelineResult], *,
            n_rows: int | None = None,
@@ -217,23 +225,20 @@ def _merge(schema: FTable, pipeline: tuple,
             (n_rows or 0, plan.response_width), jnp.float32), count=0)
     kind = partials[0].kind
     if kind == "rows":
+        # the splice runs on the host: the survivors are client data by
+        # now, and an (n, w) device array with w < 128 would be padded to
+        # 128 lanes on the TPU
         counts = [int(p.count) for p in partials]
         cpost = op_ir.crypt_post_of(pipeline) if n_rows is not None else None
+        survivors = [np.asarray(p.rows, np.float32)[:c]
+                     for p, c in zip(partials, counts)]
         if cpost is not None:
-            key = jnp.asarray(cpost.key, jnp.uint32)
-            survivors = []
-            for p, c in zip(partials, counts):
-                # undo each node's local response crypt — survivors only:
-                # they are packed at the front, and the keystream is
-                # contiguous from position 0, so decrypt cost scales with
-                # the RESULT size, not the partition size
-                buf = jnp.asarray(p.rows, jnp.float32)[:c]
-                dec = kref.ctr_crypt(buf.reshape(-1).view(jnp.uint32),
-                                     key, cpost.nonce)
-                survivors.append(dec.view(jnp.float32).reshape(buf.shape))
-        else:
-            survivors = [p.rows[:c] for p, c in zip(partials, counts)]
-        rows = jnp.concatenate(survivors, axis=0)
+            # undo each node's local response crypt — survivors only:
+            # they are packed at the front, and the keystream is
+            # contiguous from position 0, so decrypt cost scales with
+            # the RESULT size, not the partition size
+            survivors = [_ctr_host(buf, cpost) for buf in survivors]
+        rows = np.concatenate(survivors, axis=0)
         ids_list = [p.sel_ids for p in partials]
         merged_ids = None
         if all(i is not None for i in ids_list):
@@ -243,17 +248,14 @@ def _merge(schema: FTable, pipeline: tuple,
                 # hash/skew partitions interleave; range partitions come
                 # back already ordered and skip the gather entirely
                 order = np.argsort(merged_ids)  # ids unique: original order
-                rows = jnp.asarray(rows)[jnp.asarray(order)]
+                rows = rows[order]
                 merged_ids = merged_ids[order]
         count = int(rows.shape[0])
         if n_rows is not None:      # single-node-shaped packed response
-            full = jnp.zeros((n_rows, int(rows.shape[1])), jnp.float32)
-            full = full.at[:count].set(rows)
+            full = np.zeros((n_rows, int(rows.shape[1])), np.float32)
+            full[:count] = rows
             if cpost is not None:   # re-encrypt at merged stream positions
-                enc = kref.ctr_crypt(full.reshape(-1).view(jnp.uint32),
-                                     jnp.asarray(cpost.key, jnp.uint32),
-                                     cpost.nonce)
-                full = enc.view(jnp.float32).reshape(full.shape)
+                full = _ctr_host(full, cpost)
             rows = full
         return PipelineResult(kind="rows", rows=rows, count=count,
                               sel_ids=merged_ids,

@@ -37,10 +37,14 @@ accounting callbacks. Benchmarks call it inside the timed closure; the
 dispatch itself never blocks.
 
 Operator lowering is backend-aware: on TPU the Pallas kernels run inside
-the trace (their pad/layout glue becomes part of the traced program); off
-TPU — where Pallas would run in interpret mode, emulating the MXU datapath
-at ~50x cost — the same operators lower to the XLA-native `*_xla`/ref
-implementations, which tests assert byte-identical.
+the trace (their pad/layout glue becomes part of the traced program), over
+a word table held column-major, (A, n): an (n, A) array with A < 128
+would be padded to 128 lanes in HBM. Off TPU — where Pallas would run in
+interpret mode, emulating the MXU datapath at ~50x cost — the same
+operators lower to the XLA-native `*_xla`/ref implementations over (n, A)
+rows. `interpret=False` forces the kernel lowering anywhere (its kernels
+then run in interpret mode off the TPU); tests hold the two lowerings
+byte-identical.
 
 Compiled executables are cached by (schema layout, pipeline signature) —
 the analogue of Farview's precompiled partial bitstreams: "reconfiguring a
@@ -100,7 +104,9 @@ class PipelineResult:
     @property
     def rows(self):
         if self._raw is not None and "rows" in self._raw:
-            return self._raw["rows"]
+            if not self._meta.get("cm"):
+                return self._raw["rows"]
+            self.finalize()     # column-major payload: rows exist on host
         return self._rows
 
     @property
@@ -155,8 +161,17 @@ class PipelineResult:
         if self._raw is not None:
             raw, self._raw = self._raw, None
             if self.kind == "rows":
-                self._rows = raw["rows"]
                 self._count = int(raw["count"])
+                self._rows = raw["rows"]
+                if self._meta.get("cm"):
+                    # column-major (w, n) payload, laid out as rows on the
+                    # host. Only the survivors cross, unless the response
+                    # is encrypted: then its tail is keystream, not zeros.
+                    w, n = self._rows.shape
+                    ship = n if self._meta.get("sealed") else self._count
+                    rows = np.zeros((n, w), np.float32)
+                    rows[:ship] = np.asarray(self._rows[:, :ship]).T
+                    self._rows = rows
                 self._shipped = int(raw["shipped"])
                 if "ids" in raw:
                     self._ids = np.rint(np.asarray(
@@ -184,7 +199,9 @@ class PipelineResult:
             sum=raw["sum"], min=raw["min"], max=raw["max"],
             drop_key=self._meta.get("drop_key"),
             ovf_keys=np.asarray(raw["ovf_keys"][:n_ovf]),
-            ovf_vals=np.asarray(raw["ovf_vals"][:n_ovf]))
+            ovf_vals=(np.asarray(raw["ovf_vals"][:, :n_ovf]).T
+                      if self._meta.get("cm")
+                      else np.asarray(raw["ovf_vals"][:n_ovf])))
         self._shipped = int(raw["shipped"])
 
 
@@ -257,6 +274,9 @@ class CompiledPipeline:
         if self.join is not None and (self.group is not None
                                       or self.distinct is not None):
             raise ValueError("JoinSmall composes with select/project only")
+
+        # the kernel lowering holds a word table column-major, (A, n)
+        self._cm = not self.interpret and not self._str_width
 
         self.kind = ("mask" if self.regex_tbl is not None else
                      "groups" if (self.group is not None
@@ -396,16 +416,17 @@ class CompiledPipeline:
                            int(nv[b]) * int(ws[b]))
                 for b in range(int(strings.shape[0]))]
 
-    @staticmethod
-    def _split(payload: dict, b: int, nv: int) -> dict:
+    def _split(self, payload: dict, b: int, nv: int) -> dict:
         """Request b's slice of a stacked payload. Row-shaped arrays are cut
         back to the request's own length so bucket padding is invisible to
-        the client (packed survivors always fit: count <= nv)."""
+        the client (packed survivors always fit: count <= nv); column-major
+        ones along their last axis."""
         out = {}
         for k, v in payload.items():
             v = v[b]
             if k in ("rows", "mask", "ovf_keys", "ovf_vals", "ids"):
-                v = v[:nv]      # packed fronts always fit: count <= nv
+                # packed fronts always fit: count <= nv
+                v = v[..., :nv] if self._cm and v.ndim == 2 else v[:nv]
             out[k] = v
         return out
 
@@ -462,12 +483,15 @@ class CompiledPipeline:
         # drop_key is always published: select masking AND n_valid tail
         # masking both remap dropped rows to _DROP_KEY, and real keys can
         # never collide with it (ingest enforces |key| < 2^24).
-        meta = ({"drop_key": _DROP_KEY} if self.kind == "groups" else None)
+        meta = {"cm": self._cm, "sealed": self.crypt_post is not None}
+        if self.kind == "groups":
+            meta["drop_key"] = _DROP_KEY
         return PipelineResult(self.kind, read_bytes=read_bytes,
                               _raw=payload, _meta=meta)
 
     def _rows_entry(self, rows, lengths, build, row_ids):
-        return self._body(rows, lengths, None, build, row_ids, narrowed=False)
+        work = rows.T if self._cm else rows
+        return self._body(work, lengths, None, build, row_ids, narrowed=False)
 
     def _strings_entry(self, strings, lengths, n_valid, row_ids):
         # stacked (B, n, w) byte tensor: vmap the whole DFA/crypt body
@@ -482,58 +506,60 @@ class CompiledPipeline:
 
     def _pages_entry(self, buf, pages, n_valid, build, row_ids, tier, *,
                      n_rows, row_words, page_words):
-        if pages.ndim == 2:                     # stacked multi-client round
-            # `build` is closed over, not vmapped: the round shares ONE
-            # join build table, broadcast across the stacked probes.
-            # `tier` (when present) is a stacked descriptor tuple and maps
-            # with the pages — each request decodes its own cold planes
-            # inside the same vmapped body.
-            if row_ids is None:
-                def one(pg, nv, tr):
-                    return self._gather_run(buf, pg, nv, build, None,
-                                            n_rows, row_words, tr,
-                                            page_words)
-                if tier is None:
-                    return jax.vmap(lambda pg, nv: one(pg, nv, None)
-                                    )(pages, n_valid)
-                return jax.vmap(one)(pages, n_valid, tier)
+        # A flat pool's pages are copied out once, before any vmap (see
+        # `pool.read_pages`); a tiered gather decodes from the whole buffer.
+        src = buf if tier is not None else fpool.read_pages(buf, pages)
 
-            def one(pg, nv, ids, tr):
-                return self._gather_run(buf, pg, nv, build, ids,
-                                        n_rows, row_words, tr, page_words)
-            if tier is None:
-                return jax.vmap(lambda pg, nv, ids: one(pg, nv, ids, None)
-                                )(pages, n_valid, row_ids)
-            return jax.vmap(one)(pages, n_valid, row_ids, tier)
-        return self._gather_run(buf, pages, n_valid, build, row_ids,
-                                n_rows, row_words, tier, page_words)
+        def one(src, nv, ids, tr):
+            return self._gather_run(src, nv, build, ids, n_rows, row_words,
+                                    tr, page_words)
+        if pages.ndim == 1:
+            return one(src, n_valid, row_ids, tier)
+        # stacked multi-client round: `build` is closed over, not vmapped —
+        # the round shares ONE join build table, broadcast across the
+        # stacked probes. `tier` (when present) is a stacked descriptor
+        # tuple and maps with the pages — each request decodes its own
+        # cold planes inside the same vmapped body, from the shared buffer.
+        axes = (None if tier is not None else 0, 0,
+                None if row_ids is None else 0, None if tier is None else 0)
+        return jax.vmap(one, in_axes=axes)(src, n_valid, row_ids, tier)
 
-    def _gather_run(self, buf, pages, n_valid, build, row_ids,
+    def _gather_run(self, src, n_valid, build, row_ids,
                     n_rows, row_words, tier=None, page_words=None):
-        if self._columnar_read():
-            if tier is not None:
-                work = ktier.gather_columns_tiered(
-                    buf, tier, n_rows, row_words, tuple(self.proj_cols),
-                    page_words)
-            else:
-                work = fpool.gather_columns(buf, pages, n_rows, row_words,
-                                            tuple(self.proj_cols))
-            return self._body(work, None, n_valid, build, row_ids,
-                              narrowed=True)
+        """`src` is the request's copied pages (P, page_words) on a flat
+        pool, the whole pool buffer on a tiered one."""
+        narrowed = self._columnar_read()
+        cols = (tuple(self.proj_cols) if narrowed
+                else tuple(range(row_words)))
         if tier is not None:
-            rows = ktier.gather_rows_tiered(buf, tier, n_rows, row_words,
-                                            page_words)
+            work = (ktier.gather_columns_tiered(src, tier, n_rows, row_words,
+                                                cols, page_words)
+                    if narrowed else
+                    ktier.gather_rows_tiered(src, tier, n_rows, row_words,
+                                             page_words))
+            work = work.T if self._cm else work
+        elif self._cm:
+            work = fpool.columns_of(src, n_rows, row_words, cols)
+        elif narrowed:
+            work = fpool.columns_of(src, n_rows, row_words, cols).T
         else:
-            rows = fpool.gather_rows(buf, pages, n_rows, row_words)
-        return self._body(rows, None, n_valid, build, row_ids,
-                          narrowed=False)
+            work = fpool.rows_of(src, n_rows, row_words)
+        return self._body(work, None, n_valid, build, row_ids,
+                          narrowed=narrowed)
 
     def _body(self, work, lengths, n_valid, build, row_ids, *,
               narrowed: bool):
-        """The whole request pipeline as one traced program."""
+        """The whole request pipeline as one traced program.
+
+        `work` is (n, A) rows, or column-major (A, n) on the kernel
+        lowering of a word table (`_cm`): there every array stays
+        lane-dense, where (n, A) would be padded to 128 lanes on the TPU.
+        """
         self.traces += 1                         # trace-time side effect only
         xla = self.interpret                     # lowering choice (static)
-        n = work.shape[0]
+        cm = self._cm
+        ax = 0 if cm else 1                      # the column axis of `work`
+        n = work.shape[1 - ax]
         valid = (None if n_valid is None
                  else jnp.arange(n, dtype=jnp.int32) < n_valid)
 
@@ -541,11 +567,10 @@ class CompiledPipeline:
         if self.crypt_pre is not None:
             key = np.asarray(self.crypt_pre.key, np.uint32)
             nonce = self.crypt_pre.nonce
-            flat = work.reshape(-1)
             if self._str_width:
-                u32 = flat.astype(jnp.uint32)
+                u32 = work.astype(jnp.uint32)
             else:
-                u32 = jnp.asarray(flat, jnp.float32).view(jnp.uint32)
+                u32 = jnp.asarray(work, jnp.float32).view(jnp.uint32)
             if row_ids is not None:
                 # partitioned dispatch: this node holds a row *subset* of
                 # one encrypted table, so each row's keystream position is
@@ -553,17 +578,23 @@ class CompiledPipeline:
                 # local one. Gathered keystream goes through the pure-jnp
                 # reference cipher (backend-agnostic; the Pallas kernel
                 # assumes a contiguous stream).
-                w = work.shape[-1]
-                idx = (row_ids.astype(jnp.uint32)[:, None] * jnp.uint32(w)
-                       + jnp.arange(w, dtype=jnp.uint32)[None, :]).reshape(-1)
-                dec = kref.ctr_crypt(u32, jnp.asarray(key), nonce, idx=idx)
+                w = work.shape[ax]
+                base = row_ids.astype(jnp.uint32) * jnp.uint32(w)
+                word = jnp.arange(w, dtype=jnp.uint32)
+                idx = (base[None, :] + word[:, None] if cm
+                       else base[:, None] + word[None, :])
+                dec = kref.ctr_crypt(u32.reshape(-1), jnp.asarray(key), nonce,
+                                     idx=idx.reshape(-1)).reshape(u32.shape)
             elif xla:
-                dec = kref.ctr_crypt(u32, jnp.asarray(key), nonce)
+                dec = kref.ctr_crypt(u32.reshape(-1), jnp.asarray(key),
+                                     nonce).reshape(u32.shape)
+            elif cm:
+                dec = kops.crypt_cols(u32, key, nonce)
             else:
-                dec = kops.crypt(u32, key, nonce, interpret=False)
-            work = (dec.view(jnp.float32).reshape(work.shape)
-                    if not self._str_width
-                    else dec.astype(jnp.uint8).reshape(work.shape))
+                dec = kops.crypt(u32.reshape(-1), key,
+                                 nonce).reshape(u32.shape)
+            work = (dec.view(jnp.float32) if not self._str_width
+                    else dec.astype(jnp.uint8))
 
         # -- regex path (string tables) --------------------------------------
         if self.regex_tbl is not None:
@@ -573,7 +604,7 @@ class CompiledPipeline:
                                       jnp.asarray(accept))
             else:
                 mask = kops.regex_match(work, lengths, jnp.asarray(table),
-                                        jnp.asarray(accept), interpret=False)
+                                        jnp.asarray(accept))
             if valid is not None:
                 mask = mask & valid
                 # 1 byte/row decision for *valid* rows only (bucket padding
@@ -586,7 +617,7 @@ class CompiledPipeline:
         # -- smart addressing narrows columns (unless gathered narrowed) -----
         if self.smart and self.proj_cols is not None:
             if not narrowed:
-                work = work[:, np.asarray(self.proj_cols)]
+                work = jnp.take(work, np.asarray(self.proj_cols), axis=ax)
             eff_sel_ops = self.sel_ops[np.asarray(self.proj_cols)]
             eff_sel_vals = self.sel_vals[np.asarray(self.proj_cols)]
             eff_proj = np.ones((len(self.proj_cols),), np.float32)
@@ -603,16 +634,16 @@ class CompiledPipeline:
             if build is None:
                 raise ValueError("JoinSmall needs build=(keys, vals)")
             bkeys, bvals = build
-            pkeys = jnp.rint(work[:, self._col(self.join.probe_key)]
-                             ).astype(jnp.int32)
+            pkeys = jnp.rint(jnp.take(work, self._col(self.join.probe_key),
+                                      axis=ax)).astype(jnp.int32)
             if xla:
                 joined, hit = kops.hash_join_xla(pkeys, bkeys, bvals)
             else:
-                joined, hit = kops.hash_join(pkeys, bkeys, bvals,
-                                             interpret=False)
-            nb = joined.shape[1]
+                joined, hit = kops.hash_join_cols(pkeys, bkeys, bvals)
+            nb = joined.shape[ax]
             work = jnp.concatenate(
-                [work, joined, hit[:, None].astype(jnp.float32)], axis=1)
+                [work, joined, jnp.expand_dims(hit.astype(jnp.float32), ax)],
+                axis=ax)
             eff_sel_ops = np.concatenate(
                 [eff_sel_ops, np.zeros(nb, np.int32),
                  np.asarray([op_ir.OPS["=="]], np.int32)])
@@ -640,7 +671,8 @@ class CompiledPipeline:
         # encrypt — ids are transport metadata, not response payload. -------
         if row_ids is not None:
             work = jnp.concatenate(
-                [work, row_ids.astype(jnp.float32)[:, None]], axis=1)
+                [work, jnp.expand_dims(row_ids.astype(jnp.float32), ax)],
+                axis=ax)
             eff_sel_ops = np.concatenate(
                 [eff_sel_ops, np.zeros(1, np.int32)])
             eff_sel_vals = np.concatenate(
@@ -652,41 +684,25 @@ class CompiledPipeline:
             packed, count = kops.select_project_xla(
                 work, eff_sel_ops, eff_sel_vals, eff_proj, valid)
         else:
-            if valid is not None:
-                # validity as an extra ==1 predicate column through the kernel
-                work_v = jnp.concatenate(
-                    [work, valid.astype(jnp.float32)[:, None]], axis=1)
-                ops_v = np.concatenate(
-                    [eff_sel_ops, np.asarray([op_ir.OPS["=="]], np.int32)])
-                vals_v = np.concatenate(
-                    [eff_sel_vals, np.asarray([1.0], np.float32)])
-                proj_v = np.concatenate([eff_proj, np.zeros(1, np.float32)])
-                packed, count = kops.select_project(
-                    work_v, jnp.asarray(ops_v), jnp.asarray(vals_v),
-                    jnp.asarray(proj_v), interpret=False)
-                packed = packed[:, :-1]
-            else:
-                packed, count = kops.select_project(
-                    work, jnp.asarray(eff_sel_ops),
-                    jnp.asarray(eff_sel_vals), jnp.asarray(eff_proj),
-                    interpret=False)
+            packed, count = kops.select_project_cols(
+                work, eff_sel_ops, eff_sel_vals, eff_proj, n_valid)
 
         ids_packed = None
         if row_ids is not None:
-            ids_packed = packed[:, -1]
-            packed = packed[:, :-1]
+            width = packed.shape[ax] - 1
+            ids_packed = jnp.take(packed, width, axis=ax)
+            packed = jax.lax.slice_in_dim(packed, 0, width, axis=ax)
 
         # -- post-encrypt + pack ----------------------------------------------
         if self.crypt_post is not None:
             key = np.asarray(self.crypt_post.key, np.uint32)
-            u32 = packed.reshape(-1).view(jnp.uint32)
+            u32 = packed.view(jnp.uint32)
             if xla:
-                enc = kref.ctr_crypt(u32, jnp.asarray(key),
-                                     self.crypt_post.nonce)
+                enc = kref.ctr_crypt(u32.reshape(-1), jnp.asarray(key),
+                                     self.crypt_post.nonce).reshape(u32.shape)
             else:
-                enc = kops.crypt(u32, key, self.crypt_post.nonce,
-                                 interpret=False)
-            packed = enc.view(jnp.float32).reshape(packed.shape)
+                enc = kops.crypt_cols(u32, key, self.crypt_post.nonce)
+            packed = enc.view(jnp.float32)
 
         shipped = count.astype(jnp.int32) * np.int32(ncols_out * WORD_BYTES)
         out = {"rows": packed, "count": count, "shipped": shipped}
@@ -703,23 +719,23 @@ class CompiledPipeline:
             kcol = self._col(self.distinct.cols[0])
             vcols = [kcol]
             nb = self.distinct.n_buckets
-        keys = jnp.rint(work[:, kcol]).astype(jnp.int32)
-        vals = work[:, np.asarray(vcols)]
+        ax = 0 if self._cm else 1                # the column axis of `work`
+        keys = jnp.rint(jnp.take(work, kcol, axis=ax)).astype(jnp.int32)
+        vals = jnp.take(work, np.asarray(vcols), axis=ax)
         # grouping consumes only selected+valid rows: mask via sentinel key
         m = None
         if self.has_select:
             m = kref.eval_predicate(work, jnp.asarray(eff_sel_ops),
-                                    jnp.asarray(eff_sel_vals))
+                                    jnp.asarray(eff_sel_vals), col_axis=ax)
         if valid is not None:
             m = valid if m is None else (m & valid)
         if m is not None:
             keys = jnp.where(m, keys, _DROP_KEY)
-            vals = jnp.where(m[:, None], vals, 0)
+            vals = jnp.where(jnp.expand_dims(m, ax), vals, 0)
         if xla:
             res = kref.group_aggregate(keys, vals, nb)
         else:
-            res = kops.group_aggregate(keys, vals, n_buckets=nb,
-                                       interpret=False)
+            res = kops.group_aggregate_cols(keys, vals, n_buckets=nb)
         ovf = res["overflow_mask"]
         keep = ovf & (keys != _DROP_KEY)
         keep_cnt = jnp.sum(keep.astype(jnp.int32))
@@ -732,7 +748,9 @@ class CompiledPipeline:
                    + keep_cnt * np.int32((1 + len(vcols)) * WORD_BYTES))
         return {"bucket_keys": res["bucket_keys"], "count": res["count"],
                 "sum": res["sum"], "min": res["min"], "max": res["max"],
-                "ovf_keys": keys[order], "ovf_vals": vals[order],
+                "ovf_keys": keys[order],
+                "ovf_vals": (kref.take_lanes(vals, order) if self._cm
+                             else vals[order]),
                 "ovf_count": keep_cnt, "shipped": shipped}
 
 

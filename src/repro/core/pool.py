@@ -41,6 +41,41 @@ PAGE_BYTES = 2 * 1024 * 1024
 
 
 # ---------------------------------------------------------------- read path
+def read_pages(buf: jnp.ndarray, pages: jnp.ndarray) -> jnp.ndarray:
+    """Copy pages out of the pool -> pages.shape + (page_words,).
+
+    One dynamic slice per page, in sequence. A row gather `buf[pages]`
+    with page-sized slices makes the TPU compiler split the whole pool
+    column-wise into temporaries (pool-sized HBM, a minute of compile);
+    so does any per-page slice under `vmap`, which is why the stacked
+    dispatch calls this on its (B, P) page matrix before vmapping.
+    """
+    flat = pages.reshape(-1)
+    out = jax.lax.map(
+        lambda p: jax.lax.dynamic_index_in_dim(buf, p, keepdims=False), flat)
+    return out.reshape(pages.shape + buf.shape[1:])
+
+
+def rows_of(paged: jnp.ndarray, n_rows: int, row_words: int) -> jnp.ndarray:
+    """A table's copied pages (P, page_words) -> (n_rows, row_words) f32."""
+    flat = paged.reshape(-1)
+    return flat[: n_rows * row_words].reshape(n_rows, row_words)
+
+
+def columns_of(paged: jnp.ndarray, n_rows: int, row_words: int,
+               col_idx: tuple[int, ...]) -> jnp.ndarray:
+    """Smart addressing (paper §5.2) as strided slices of a table's copied
+    pages: only the projected columns' words. Returns column-major
+    (k, n_rows) — lane-dense on the TPU, where an (n_rows, k) array would
+    be padded to 128 lanes."""
+    if n_rows == 0:
+        return jnp.zeros((len(col_idx), 0), paged.dtype)
+    flat = paged.reshape(-1)
+    end = (n_rows - 1) * row_words + 1
+    return jnp.stack([jax.lax.slice(flat, (c,), (c + end,), (row_words,))
+                      for c in col_idx])
+
+
 def gather_rows(buf: jnp.ndarray, pages: jnp.ndarray, n_rows: int,
                 row_words: int) -> jnp.ndarray:
     """Device-resident page gather -> (n_rows, row_words) f32.
@@ -49,17 +84,24 @@ def gather_rows(buf: jnp.ndarray, pages: jnp.ndarray, n_rows: int,
     traced program — the fused pipeline executable calls this directly so
     the pool read is part of the same compiled dispatch.
     """
-    flat = buf[pages].reshape(-1)
-    return flat[: n_rows * row_words].reshape(n_rows, row_words)
+    return rows_of(read_pages(buf, pages), n_rows, row_words)
 
 
 def gather_columns(buf: jnp.ndarray, pages: jnp.ndarray, n_rows: int,
                    row_words: int, col_idx: tuple[int, ...]) -> jnp.ndarray:
-    """Smart addressing (paper §5.2) as a device-resident strided gather:
-    only the projected columns' words leave DRAM. Returns (n_rows, k)."""
-    flat = buf[pages].reshape(-1)
-    base = jnp.arange(n_rows, dtype=jnp.int32) * row_words
-    return jnp.stack([flat[base + c] for c in col_idx], axis=1)
+    """`columns_of` straight from the pool, as rows: (n_rows, k)."""
+    return columns_of(read_pages(buf, pages), n_rows, row_words, col_idx).T
+
+
+@jax.jit
+def _write_pages(buf, pages, data):
+    """The pool with data (P, page_words) written over its pages: one
+    dynamic update per page, the write-side twin of `read_pages`."""
+    return jax.lax.fori_loop(
+        0, pages.shape[0],
+        lambda i, b: jax.lax.dynamic_update_index_in_dim(b, data[i],
+                                                         pages[i], 0),
+        buf)
 
 
 @functools.partial(jax.jit, static_argnames=("n_rows", "row_words"))
@@ -265,14 +307,20 @@ class FarPool:
             # writes land on raw pages only: promote first (a written table
             # is hot by definition; the heat ledger will re-demote later)
             self.promote_table(ft)
-        flat = jnp.asarray(words, jnp.float32).reshape(-1)
         n_pages = len(ft.pages)
-        padded = jnp.zeros((n_pages * self.page_words,), jnp.float32)
-        padded = padded.at[:flat.shape[0]].set(flat)
-        pages = jnp.asarray(ft.pages, jnp.int32)
-        self.buf = self.buf.at[pages].set(
-            padded.reshape(n_pages, self.page_words))
-        self.stats.bytes_written += int(flat.shape[0]) * WORD_BYTES
+        if isinstance(words, np.ndarray):
+            # lay host rows out as pages on the host: an (n, A) device
+            # array with A < 128 would be padded to 128 lanes on the TPU
+            flat = words.astype(np.float32, copy=False).reshape(-1)
+            data = np.zeros((n_pages, self.page_words), np.float32)
+            data.reshape(-1)[:flat.size] = flat
+        else:
+            flat = jnp.asarray(words, jnp.float32).reshape(-1)
+            data = jnp.pad(flat, (0, n_pages * self.page_words - flat.size)
+                           ).reshape(n_pages, self.page_words)
+        self.buf = _write_pages(self.buf, jnp.asarray(ft.pages, jnp.int32),
+                                jnp.asarray(data))
+        self.stats.bytes_written += int(flat.size) * WORD_BYTES
 
     def gather_rows(self, pages, n_rows: int, row_words: int) -> jnp.ndarray:
         """Device-resident read path (no accounting): one jitted gather."""
@@ -349,10 +397,8 @@ class FarPool:
         own = [p for p in ft.pages if p // self.chunk == shard]
         if not own:
             return jnp.zeros((0, ft.row_words), jnp.float32)
-        pages = jnp.asarray(own, jnp.int32)
-        flat = self.buf[pages].reshape(-1)
-        rows = flat.reshape(-1, ft.row_words)
-        return rows
+        flat = read_pages(self.buf, jnp.asarray(own, jnp.int32)).reshape(-1)
+        return flat.reshape(-1, ft.row_words)
 
     # -------------------------------------------------- tiering (hot / cold)
     def is_tiered(self, ft: FTable) -> bool:

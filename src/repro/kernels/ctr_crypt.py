@@ -22,6 +22,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK = (256, 128)
+DEFAULT_COL_BLOCK = 512
 _ROTS = (13, 15, 26, 6, 17, 29, 16, 24)
 _PARITY = np.uint32(0x1BD11BDA)
 
@@ -44,18 +45,20 @@ def _threefry2x32(k0, k1, c0, c1):
     return x0, x1
 
 
-def _kernel(block_shape, data_ref, key_ref, out_ref):
+def _kernel(block_shape, row_words, data_ref, key_ref, out_ref):
     rows, cols = block_shape
     data = data_ref[...]
     k0 = key_ref[0, 0]
     k1 = key_ref[0, 1]
     nonce = key_ref[0, 2]
-    step = pl.program_id(0)
+    step = pl.program_id(0).astype(jnp.uint32)
 
-    base = (step * rows * cols).astype(jnp.uint32)
     ir = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 0)
     ic = jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 1)
-    pos = base + ir * np.uint32(cols) + ic
+    if row_words is None:       # contiguous row-major stream
+        pos = step * np.uint32(rows * cols) + ir * np.uint32(cols) + ic
+    else:                       # column-major (row_words, N): word [c, r]
+        pos = (step * np.uint32(cols) + ic) * np.uint32(row_words) + ir
     ctr = pos >> np.uint32(1)
     lane = pos & np.uint32(1)
     s0, s1 = _threefry2x32(k0, k1, ctr, jnp.full_like(ctr, nonce))
@@ -72,7 +75,7 @@ def ctr_crypt(data: jnp.ndarray, key: jnp.ndarray, *,
     n, c = data.shape
     rows, cols = block
     assert n % rows == 0 and c == cols, (data.shape, block)
-    kern = functools.partial(_kernel, block)
+    kern = functools.partial(_kernel, block, None)
     return pl.pallas_call(
         kern,
         grid=(n // rows,),
@@ -84,3 +87,26 @@ def ctr_crypt(data: jnp.ndarray, key: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((n, c), jnp.uint32),
         interpret=interpret,
     )(data, key)
+
+
+@functools.partial(jax.jit, static_argnames=("block_cols", "interpret"))
+def ctr_crypt_cols(data_t: jnp.ndarray, key: jnp.ndarray, *,
+                   block_cols: int = DEFAULT_COL_BLOCK,
+                   interpret: bool = True):
+    """The same cipher over a column-major table: data_t (A, N) uint32
+    holds word c of row r at [c, r], which is stream position r*A + c of
+    the row-major flattening. N % block_cols == 0. Involutive."""
+    a, n = data_t.shape
+    assert n % block_cols == 0, (data_t.shape, block_cols)
+    kern = functools.partial(_kernel, (a, block_cols), a)
+    return pl.pallas_call(
+        kern,
+        grid=(n // block_cols,),
+        in_specs=[
+            pl.BlockSpec((a, block_cols), lambda i: (0, i)),
+            pl.BlockSpec((1, 4), lambda i: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((a, block_cols), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((a, n), jnp.uint32),
+        interpret=interpret,
+    )(data_t, key)

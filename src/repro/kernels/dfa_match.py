@@ -30,29 +30,28 @@ ALPHA = 256
 
 def _kernel(n_states, seq_len, chars_ref, len_ref, table_ref, accept_ref,
             out_ref):
-    chars = chars_ref[...]                                    # (L, R) int32
     lens = len_ref[...]                                       # (1, R) int32
     table_t = table_ref[...]                                  # (256, S) f32 (T^t)
     accept = accept_ref[...]                                  # (1, S) f32
-    r = chars.shape[1]
+    r = lens.shape[1]
     s = n_states
 
     iota_s = jax.lax.broadcasted_iota(jnp.int32, (s, r), 0)
     iota_c = jax.lax.broadcasted_iota(jnp.int32, (ALPHA, r), 0)
 
-    def step(t, state):
-        ch = jax.lax.dynamic_slice(chars, (t, 0), (1, r))     # (1, R)
-        st_oh = (state[None, :] == iota_s).astype(jnp.float32)    # (S, R)
+    def step(t, state):                                       # state (1, R)
+        ch = chars_ref[pl.ds(t, 1), :]                        # (1, R)
+        st_oh = (state == iota_s).astype(jnp.float32)             # (S, R)
         ch_oh = (ch == iota_c).astype(jnp.float32)                # (256, R)
         u = jax.lax.dot(table_t, st_oh,
                         precision=jax.lax.Precision.HIGHEST)      # (256, R)
-        nxt = jnp.sum(u * ch_oh, axis=0)                          # (R,)
+        nxt = jnp.sum(u * ch_oh, axis=0, keepdims=True)           # (1, R)
         nxt = jnp.round(nxt).astype(jnp.int32)
-        return jnp.where(t < lens[0], nxt, state)
+        return jnp.where(t < lens, nxt, state)
 
     state = jax.lax.fori_loop(0, seq_len, step,
-                              jnp.zeros((r,), jnp.int32))
-    st_oh = (state[None, :] == iota_s).astype(jnp.float32)
+                              jnp.zeros((1, r), jnp.int32))
+    st_oh = (state == iota_s).astype(jnp.float32)
     acc = jax.lax.dot(accept, st_oh,
                       precision=jax.lax.Precision.HIGHEST)         # (1, R)
     out_ref[...] = (acc > 0.5).astype(jnp.int32)
@@ -66,24 +65,23 @@ def dfa_match(chars_t: jnp.ndarray, lengths: jnp.ndarray,
               interpret: bool = True):
     """chars_t: (L, N) int32 transposed strings; lengths: (1, N) int32;
     table_t: (256, S) f32 transition table transpose; accept: (1, S) f32.
-    N % block_rows == 0. Returns match mask (1, N)... shaped (nb, block_rows).
+    N % block_rows == 0. Returns the (N,) int32 match mask.
     """
     l, n = chars_t.shape
     s = table_t.shape[1]
     assert n % block_rows == 0
-    nb = n // block_rows
     kern = functools.partial(_kernel, s, l)
     out = pl.pallas_call(
         kern,
-        grid=(nb,),
+        grid=(n // block_rows,),
         in_specs=[
             pl.BlockSpec((l, block_rows), lambda i: (0, i)),
             pl.BlockSpec((1, block_rows), lambda i: (0, i)),
             pl.BlockSpec((ALPHA, s), lambda i: (0, 0)),
             pl.BlockSpec((1, s), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_rows), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, block_rows), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_rows), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
         interpret=interpret,
     )(chars_t, lengths, table_t, accept)
     return out.reshape(n)

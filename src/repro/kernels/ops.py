@@ -42,43 +42,46 @@ def select_project(table, sel_ops, sel_vals, proj_mask, *,
 
     Returns (packed (N, A) f32 globally compacted, count scalar i32).
     """
+    packed_t, total = select_project_cols(
+        jnp.asarray(table).T, sel_ops, sel_vals, proj_mask,
+        block_rows=block_rows, interpret=interpret)
+    return packed_t.T, total
+
+
+def select_project_cols(table_t, sel_ops, sel_vals, proj_mask, n_valid=None,
+                        *, block_rows: int = 256,
+                        interpret: bool | None = None):
+    """Column-major select_project: table_t (A, N) f32, one row per lane.
+
+    Rows at or past `n_valid` (optional traced scalar) never survive.
+    Returns (packed_t (A, N) f32 globally compacted, count scalar i32).
+    """
     if interpret is None:
         interpret = _interpret_default()
-    n, a = table.shape
-    t = _pad_to(_pad_to(table.astype(jnp.float32), 1, 128), 0, block_rows)
-    c = t.shape[1]
-    # Padded columns must not affect the predicate: pad ops with OP_SKIP.
-    ops2 = _pad_to(sel_ops.astype(jnp.int32)[None, :], 1, 128,
+    a, n = table_t.shape
+    if n == 0:
+        return jnp.zeros((a, 0), jnp.float32), jnp.int32(0)
+    t = _pad_to(_pad_to(table_t.astype(jnp.float32), 0, 8), 1, block_rows)
+    # padded columns must not affect the predicate: pad ops with OP_SKIP
+    ops2 = _pad_to(jnp.asarray(sel_ops, jnp.int32)[:, None], 0, 8,
                    value=ref.OP_SKIP)
-    vals2 = _pad_to(sel_vals.astype(jnp.float32)[None, :], 1, 128)
-    proj2 = _pad_to(proj_mask.astype(jnp.float32)[None, :], 1, 128)
-    # Padded rows must not match: force a row of zeros to fail via an
-    # explicit valid-row column? Simpler: padded rows are all-zero; make them
-    # fail by post-masking counts — we instead mask them here.
+    vals2 = _pad_to(jnp.asarray(sel_vals, jnp.float32)[:, None], 0, 8)
+    proj2 = _pad_to(jnp.asarray(proj_mask, jnp.float32)[:, None], 0, 8)
+    limit = (jnp.int32(n) if n_valid is None
+             else jnp.minimum(jnp.asarray(n_valid, jnp.int32), n))
     packed_b, counts = _sp.select_project(t, ops2, vals2, proj2,
+                                          limit.reshape(1, 1),
                                           block_rows=block_rows,
                                           interpret=interpret)
-    np_rows = t.shape[0]
-    nb = counts.shape[0]
-    # Padded tail rows are all-zero; if the predicate accepts a zero row they
-    # matched spuriously. Stable compaction puts them *after* every real
-    # survivor of their (last) block, so trimming the count is exact.
-    rows_in_block = jnp.minimum(
-        block_rows, jnp.maximum(0, n - jnp.arange(nb) * block_rows))
-    zero_row = jnp.zeros((1, c), t.dtype)
-    zero_match = ref.eval_predicate(zero_row, ops2[0], vals2[0])[0]
-    pad_rows = (block_rows - rows_in_block).astype(jnp.int32)
-    counts = counts[:, 0].astype(jnp.int32) - jnp.where(zero_match, pad_rows, 0)
     # --- stitch blocks (the paper's length-prefixed response packets) ------
-    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                               jnp.cumsum(counts)[:-1]])
-    total = jnp.sum(counts)
-    blk = jnp.arange(np_rows, dtype=jnp.int32) // block_rows
-    off = jnp.arange(np_rows, dtype=jnp.int32) % block_rows
-    valid = off < counts[blk]
-    dest = jnp.where(valid, offsets[blk] + off, np_rows)  # OOB => dropped
-    out = jnp.zeros_like(packed_b).at[dest].set(packed_b, mode="drop")
-    return out[:n, :a], total
+    # output row p comes from the first block whose running end exceeds p
+    ends = jnp.cumsum(counts)
+    total = ends[-1]
+    p = jnp.arange(n, dtype=jnp.int32)
+    blk = jnp.minimum(jnp.searchsorted(ends, p, side="right"),
+                      counts.shape[0] - 1).astype(jnp.int32)
+    src = blk * block_rows + p - (ends[blk] - counts[blk])
+    return jnp.where(p < total, ref.take_lanes(packed_b[:a], src), 0.0), total
 
 
 # ---------------------------------------------------------------------------
@@ -91,14 +94,24 @@ def group_aggregate(keys, values, *, n_buckets: int = 1024,
     Overflow rows (bucket collisions) are returned for client-side merge,
     mirroring the paper's cuckoo-overflow contract.
     """
+    return group_aggregate_cols(keys, jnp.asarray(values).T,
+                                n_buckets=n_buckets, block_rows=block_rows,
+                                interpret=interpret)
+
+
+def group_aggregate_cols(keys, values_t, *, n_buckets: int = 1024,
+                         block_rows: int = 256,
+                         interpret: bool | None = None):
+    """`group_aggregate` over column-major values_t (V, N): lane-dense, for
+    the fused pipeline's column layout. Same result dict."""
     if interpret is None:
         interpret = _interpret_default()
     n = keys.shape[0]
-    v = values.shape[1]
-    kp = _pad_to(keys.astype(jnp.int32)[:, None], 0, block_rows,
+    v = values_t.shape[0]
+    kp = _pad_to(jnp.asarray(keys, jnp.int32), 0, block_rows,
                  value=ref.KEY_SENTINEL + 1)  # sentinel+1: a real-ish key
-    vp = _pad_to(values.astype(jnp.float32), 0, block_rows)
-    vp = _pad_to(vp, 1, 128)
+    vp = _pad_to(_pad_to(jnp.asarray(values_t, jnp.float32), 1, block_rows),
+                 0, 8)
     bkey, cnt, s, mn, mx, ovf = _hg.group_aggregate(
         kp, vp, n_buckets=n_buckets,
         block_rows=block_rows, interpret=interpret)
@@ -108,18 +121,17 @@ def group_aggregate(keys, values, *, n_buckets: int = 1024,
     if npad:
         pad_key = jnp.int32(ref.KEY_SENTINEL + 1)
         pb = ref.bucket_of(pad_key[None], n_buckets)[0]
-        owned_pad = bkey[pb, 0] == pad_key
+        owned_pad = bkey[pb] == pad_key
         # they contributed `npad` count and zero sums (values padded w/ 0)
-        cnt = cnt.at[pb, 0].add(jnp.where(owned_pad, -npad, 0))
-        empty_now = owned_pad & (cnt[pb, 0] == 0)
-        bkey = bkey.at[pb, 0].set(jnp.where(empty_now, ref.KEY_SENTINEL,
-                                            bkey[pb, 0]))
+        cnt = cnt.at[pb].add(jnp.where(owned_pad, -npad, 0))
+        empty_now = owned_pad & (cnt[pb] == 0)
+        bkey = bkey.at[pb].set(jnp.where(empty_now, ref.KEY_SENTINEL,
+                                         bkey[pb]))
         # min/max may be polluted by pad zeros when the pad key owns pb; that
         # bucket is dropped client-side if empty; if the pad key collided
         # with a real key, pads are overflow rows (handled below).
-    ovf = ovf[:n, 0].astype(bool)
-    return dict(bucket_keys=bkey[:, 0], count=cnt[:, 0], sum=s[:, :v],
-                min=mn[:, :v], max=mx[:, :v], overflow_mask=ovf)
+    return dict(bucket_keys=bkey, count=cnt, sum=s[:, :v],
+                min=mn[:, :v], max=mx[:, :v], overflow_mask=ovf[:n])
 
 
 def group_aggregate_full(keys, values, *, n_buckets: int = 1024,
@@ -220,6 +232,20 @@ def crypt(data_u32, key2_u32, nonce: int, *, interpret: bool | None = None):
     return y.reshape(-1)[:n]
 
 
+def crypt_cols(data_t_u32, key2_u32, nonce: int, *,
+               interpret: bool | None = None):
+    """`crypt` of a column-major table: data_t (A, N) uint32, word c of row
+    r at [c, r] — the same keystream as `crypt` of the (N, A) row-major
+    flattening, without materializing it."""
+    if interpret is None:
+        interpret = _interpret_default()
+    n = data_t_u32.shape[1]
+    x = _pad_to(jnp.asarray(data_t_u32, jnp.uint32), 1, _ctr.DEFAULT_COL_BLOCK)
+    key = jnp.array([[int(key2_u32[0]), int(key2_u32[1]), nonce & 0xFFFFFFFF,
+                      0]], dtype=jnp.uint32)
+    return _ctr.ctr_crypt_cols(x, key, interpret=interpret)[:, :n]
+
+
 # ---------------------------------------------------------------------------
 # far-KV decode attention
 # ---------------------------------------------------------------------------
@@ -265,6 +291,15 @@ def hash_join(probe_keys, build_keys, build_vals, *, block_rows: int = 256,
     Inner join against a small build table resident in VMEM (paper
     §Conclusions future work). Returns (joined (N, V), hit (N,) bool).
     """
+    joined_t, hit = hash_join_cols(probe_keys, build_keys, build_vals,
+                                   block_rows=block_rows, interpret=interpret)
+    return joined_t.T, hit
+
+
+def hash_join_cols(probe_keys, build_keys, build_vals, *,
+                   block_rows: int = 256, interpret: bool | None = None):
+    """`hash_join` with the joined values column-major: (joined_t (V, N),
+    hit (N,) bool) — lane-dense, for the fused pipeline's column layout."""
     if interpret is None:
         interpret = _interpret_default()
     if not isinstance(build_keys, jax.core.Tracer):
@@ -275,15 +310,15 @@ def hash_join(probe_keys, build_keys, build_vals, *, block_rows: int = 256,
     n = probe_keys.shape[0]
     k, v = build_vals.shape
     if k == 0:      # empty co-partitioned build shard: nothing matches
-        return (jnp.zeros((n, v), jnp.float32), jnp.zeros((n,), bool))
-    pk = _pad_to(probe_keys.astype(jnp.int32)[:, None], 0, block_rows,
+        return (jnp.zeros((v, n), jnp.float32), jnp.zeros((n,), bool))
+    pk = _pad_to(jnp.asarray(probe_keys, jnp.int32)[None, :], 1, block_rows,
                  value=ref.KEY_SENTINEL)        # sentinel never matches
-    bkp = _pad_to(build_keys.astype(jnp.int32)[:, None], 0, 8,
+    bkp = _pad_to(jnp.asarray(build_keys, jnp.int32)[:, None], 0, 8,
                   value=ref.KEY_SENTINEL + 1)   # distinct pad key
-    bvp = _pad_to(_pad_to(build_vals.astype(jnp.float32), 0, 8), 1, 128)
-    joined, hit = _hj.hash_join(pk, bkp, bvp, block_rows=block_rows,
-                                interpret=interpret)
-    return joined[:n, :v], hit[:n, 0].astype(bool)
+    bvp = _pad_to(_pad_to(jnp.asarray(build_vals, jnp.float32).T, 0, 8), 1, 8)
+    joined_t, hit = _hj.hash_join(pk, bkp, bvp, block_rows=block_rows,
+                                  interpret=interpret)
+    return joined_t[:v, :n], hit[0, :n].astype(bool)
 
 
 # ---------------------------------------------------------------------------

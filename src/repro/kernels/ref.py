@@ -19,17 +19,19 @@ KEY_SENTINEL = np.iinfo(np.int32).min  # "empty bucket" marker (hash_group)
 # select_project
 # ---------------------------------------------------------------------------
 def eval_predicate(table: jnp.ndarray, sel_ops: jnp.ndarray,
-                   sel_vals: jnp.ndarray) -> jnp.ndarray:
+                   sel_vals: jnp.ndarray, *, col_axis: int = 1) -> jnp.ndarray:
     """AND-of-per-column-comparisons predicate.
 
-    table: (N, A) float32/int32 columns.
+    table: (N, A) float32/int32 columns — or (A, N) with col_axis=0.
     sel_ops: (A,) int32 op codes (OP_SKIP disables the column).
     sel_vals: (A,) same dtype as table, comparison constants.
     Returns (N,) bool mask.
     """
+    shape = [1, 1]
+    shape[col_axis] = -1
     col = table
-    val = sel_vals[None, :]
-    ops = sel_ops[None, :]
+    val = sel_vals.reshape(shape)
+    ops = sel_ops.reshape(shape)
     per_col = jnp.where(
         ops == OP_LT, col < val,
         jnp.where(ops == OP_LE, col <= val,
@@ -38,7 +40,7 @@ def eval_predicate(table: jnp.ndarray, sel_ops: jnp.ndarray,
                                       jnp.where(ops == OP_EQ, col == val,
                                                 jnp.where(ops == OP_NE, col != val,
                                                           True))))))
-    return jnp.all(per_col, axis=1)
+    return jnp.all(per_col, axis=col_axis)
 
 
 def select_project(table: jnp.ndarray, sel_ops: jnp.ndarray,
@@ -66,6 +68,14 @@ def bucket_of(keys: jnp.ndarray, n_buckets: int) -> jnp.ndarray:
     h = (keys.astype(jnp.uint32) * jnp.uint32(0x9E3779B1))
     shift = 32 - int(np.log2(n_buckets))
     return (h >> shift).astype(jnp.int32)
+
+
+def take_lanes(x_t: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """x_t[:, idx] for a column-major (A, N) table, as one 1-D gather per
+    row: a 2-D column gather under vmap (stacked rounds) takes the TPU
+    compiler about 20x longer to compile."""
+    return jnp.stack([jnp.take(x_t[c], idx, mode="clip")
+                      for c in range(x_t.shape[0])])
 
 
 def sort_by_bucket(bucket: jnp.ndarray, n_buckets: int):

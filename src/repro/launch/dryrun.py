@@ -141,9 +141,8 @@ def _mem_dict(compiled) -> dict:
 
 
 def _cost_dict(compiled) -> dict:
-    from repro.jax_compat import cost_analysis
     try:
-        ca = cost_analysis(compiled)
+        ca = compiled.cost_analysis()
     except Exception:
         return {}
     keep = ("flops", "bytes accessed", "transcendentals", "optimal_seconds")

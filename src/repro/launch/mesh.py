@@ -12,7 +12,16 @@ targets.
 """
 from __future__ import annotations
 
-from repro.jax_compat import make_mesh, set_mesh  # noqa: F401  (re-export)
+import jax
+from jax.sharding import AxisType
+
+set_mesh = jax.set_mesh         # re-export: callers install a mesh from here
+
+
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with Auto axis types: the compiler propagates the
+    sharding, which every sharded entry point in this repo expects."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -52,6 +52,8 @@ def main() -> None:
         ap.error("--pool-nodes must be >= 1")
     if args.listen and args.connect:
         ap.error("--listen and --connect are mutually exclusive")
+    from repro import compile_cache
+    compile_cache.enable()
 
     from repro.configs import get_config
     from repro.configs.base import smoke_config

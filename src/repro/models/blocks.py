@@ -22,9 +22,9 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from repro.configs.base import ModelConfig
-from repro.jax_compat import shard_map
 from repro.models import layers as L
 from repro.models import mamba2 as M2
 from repro.models import moe as MOE

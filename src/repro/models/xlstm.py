@@ -19,8 +19,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from repro.jax_compat import shard_map
 from repro.models.layers import dense_init, init_rmsnorm, rms_norm
 
 

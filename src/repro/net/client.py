@@ -82,8 +82,14 @@ class RemotePending:
         self.error: Exception | None = None
 
     def _attach(self, payload: dict) -> None:
+        rows = payload.get("rows")
+        if "n_rows" in payload:         # survivors only: restore the tail
+            full = np.zeros((int(payload["n_rows"]),) + rows.shape[1:],
+                            rows.dtype)
+            full[: rows.shape[0]] = rows
+            rows = full
         res = PipelineResult(
-            payload["kind"], rows=payload.get("rows"),
+            payload["kind"], rows=rows,
             count=payload.get("count"), groups=payload.get("groups"),
             mask=payload.get("mask"),
             shipped_bytes=int(payload.get("shipped", 0)),
@@ -346,7 +352,8 @@ class RemoteNodeHandle:
         if self._dead or self._sock is None:
             self._ensure_conn(op)
         try:
-            self._sock.sendall(wire.encode_frame(ftype, req_id, obj))
+            self._sock.sendall(wire.encode_frame(
+                ftype, req_id, obj, max_payload=self.max_payload))
         except (OSError, ValueError) as e:
             raise self._die(op) from e
 

@@ -50,6 +50,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import client as fv
 from repro.net import wire
 
@@ -71,7 +72,13 @@ def _result_payload(res) -> dict:
            "shipped": int(res._shipped or 0),
            "read": int(res.read_bytes or 0)}
     if res.rows is not None:
-        out["rows"] = np.asarray(res.rows)
+        rows = np.asarray(res.rows)
+        if not res._meta.get("sealed") and res._count is not None:
+            # survivors only; the client restores the zero tail. A sealed
+            # (post-encrypted) response ships whole: its tail is keystream
+            out["n_rows"] = int(rows.shape[0])
+            rows = rows[: res._count]
+        out["rows"] = rows
     if res._ids is not None:
         out["sel_ids"] = np.asarray(res._ids)
     if res.mask is not None:
@@ -408,7 +415,8 @@ class FViewServer:
     # ----------------------------------------------------------- connection
     async def _send(self, conn: _Conn, ftype: int, req_id: int,
                     obj=None) -> None:
-        data = wire.encode_frame(ftype, req_id, obj)
+        data = wire.encode_frame(ftype, req_id, obj,
+                                 max_payload=self.max_payload)
         async with conn.wlock:
             conn.writer.write(data)
             try:
@@ -685,6 +693,7 @@ def main(argv=None) -> None:
     ap.add_argument("--flush-interval-ms", type=float, default=2.0)
     ap.add_argument("--log", default=None, help="append server log here")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     server = FViewServer(
         host=args.host, port=args.port,
         capacity_bytes=args.capacity_mb * 2**20, n_regions=args.regions,

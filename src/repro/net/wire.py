@@ -289,11 +289,15 @@ def decode_value(buf: bytes):
 
 
 # ------------------------------------------------------------------- framing
-def encode_frame(ftype: int, req_id: int, obj=None) -> bytes:
+def encode_frame(ftype: int, req_id: int, obj=None, *,
+                 max_payload: int = MAX_PAYLOAD) -> bytes:
+    """One frame; `max_payload` is the bound the connection was set up
+    with (the peer refuses anything larger)."""
     payload = b"" if obj is None else encode_value(obj)
-    if len(payload) > MAX_PAYLOAD:
+    if len(payload) > max_payload:
         raise ProtocolError(
-            f"payload of {len(payload)} bytes exceeds MAX_PAYLOAD")
+            f"payload of {len(payload)} bytes exceeds the "
+            f"{max_payload}-byte bound")
     hdr = HEADER.pack(MAGIC, VERSION, ftype, req_id, len(payload))
     # CRC over header AND payload: a corrupted request id (misrouted
     # response) is as wrong as a corrupted byte in an ndarray

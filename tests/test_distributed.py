@@ -180,7 +180,6 @@ def test_hlo_analyzer_trip_scaling():
     res = run_in_subprocess("""
     import json
     import jax, jax.numpy as jnp
-    from repro.jax_compat import cost_analysis
     from repro.launch.hlo_analysis import analyze
     def scanned(x, ws):
         return jax.lax.scan(lambda c, w: (c @ w, None), x, ws)[0]
@@ -188,7 +187,7 @@ def test_hlo_analyzer_trip_scaling():
     w = jax.ShapeDtypeStruct((10, 256, 256), jnp.float32)
     compiled = jax.jit(scanned).lower(x, w).compile()
     a = analyze(compiled.as_text())
-    raw = cost_analysis(compiled)["flops"]
+    raw = compiled.cost_analysis()["flops"]
     print("RESULT:" + json.dumps({"scaled": a["flops"], "raw": raw}))
     """)
     expect = 10 * 2 * 256 ** 3

@@ -3,9 +3,10 @@ KV pool primitives used by the serving stack."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
+from jax import shard_map
 
 from repro.core import far_kv
-from repro.jax_compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.kernels import ref as kref
 
 

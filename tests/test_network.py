@@ -15,7 +15,9 @@ Two harness modes, same tests:
   * `FARVIEW_NET_SUBPROCESS=1` — every server is a REAL
     `python -m repro.net.server` subprocess and the kill tests are
     SIGKILL. The CI `server-smoke` lane runs this mode; server logs go
-    to `$FARVIEW_NET_LOG_DIR` for the failure artifact.
+    to `$FARVIEW_NET_LOG_DIR` for the failure artifact. It is a CPU test
+    mode: a chip belongs to one process, so on a TPU the servers run as
+    threads of the process that holds it (as `chip_smoke.py` does).
 
 Backpressure is part of the contract too: past the admission bound a
 SUBMIT is answered with a typed `OVERLOADED` frame (`OverloadedError`

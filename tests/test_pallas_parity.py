@@ -16,6 +16,17 @@ from repro.kernels import hash_group as hg
 from repro.kernels import ref as kref
 
 
+def _raw_group(keys, vals, nb):
+    """The raw kernel on column-major values, padded to its 8-sublane
+    contract; sum/min/max come back (B, V)."""
+    v = vals.shape[1]
+    vals_t = np.zeros((-(-v // 8) * 8, len(keys)), np.float32)
+    vals_t[:v] = vals.T
+    bk, cnt, s, mn, mx, ovf = hg.group_aggregate(
+        jnp.asarray(keys), jnp.asarray(vals_t), n_buckets=nb, interpret=True)
+    return bk, cnt, s[:, :v], mn[:, :v], mx[:, :v], ovf
+
+
 @pytest.mark.parametrize("n,card,nb,v", [
     (256, 10, 64, 1), (1024, 300, 128, 2), (512, 512, 32, 3),
     (2048, 7, 1024, 2),
@@ -25,22 +36,20 @@ def test_hash_group_raw_fields_vs_ref(rng, n, card, nb, v):
     bit-identical; float aggregates match to tree-merge rounding."""
     keys = rng.integers(-card, card, size=n).astype(np.int32)
     vals = rng.normal(size=(n, v)).astype(np.float32)
-    bk, cnt, s, mn, mx, ovf = hg.group_aggregate(
-        jnp.asarray(keys[:, None]), jnp.asarray(vals),
-        n_buckets=nb, interpret=True)
+    bk, cnt, s, mn, mx, ovf = _raw_group(keys, vals, nb)
     r = kref.group_aggregate(jnp.asarray(keys), jnp.asarray(vals), nb)
-    np.testing.assert_array_equal(np.asarray(bk[:, 0]),
+    np.testing.assert_array_equal(np.asarray(bk),
                                   np.asarray(r["bucket_keys"]))
-    np.testing.assert_array_equal(np.asarray(cnt[:, 0]),
+    np.testing.assert_array_equal(np.asarray(cnt),
                                   np.asarray(r["count"]))
-    np.testing.assert_array_equal(np.asarray(ovf[:, 0]).astype(bool),
+    np.testing.assert_array_equal(np.asarray(ovf),
                                   np.asarray(r["overflow_mask"]))
     np.testing.assert_allclose(np.asarray(s), np.asarray(r["sum"]),
                                rtol=1e-4, atol=1e-4)
     # min/max of UNCLAIMED buckets carry the implementations' respective
     # identities (kernel 3.0e38 vs ref finfo.max) and are dropped by every
     # consumer; compare claimed buckets only
-    claimed = np.asarray(cnt[:, 0]) > 0
+    claimed = np.asarray(cnt) > 0
     np.testing.assert_allclose(np.asarray(mn)[claimed],
                                np.asarray(r["min"])[claimed], rtol=1e-6)
     np.testing.assert_allclose(np.asarray(mx)[claimed],
@@ -51,11 +60,9 @@ def test_hash_group_integer_data_bit_identical(rng):
     """Integer-valued f32 data: every field, sums included, is exact."""
     keys = rng.integers(0, 100, size=1024).astype(np.int32)
     vals = rng.integers(-50, 50, size=(1024, 2)).astype(np.float32)
-    bk, cnt, s, mn, mx, ovf = hg.group_aggregate(
-        jnp.asarray(keys[:, None]), jnp.asarray(vals),
-        n_buckets=128, interpret=True)
+    bk, cnt, s, mn, mx, ovf = _raw_group(keys, vals, 128)
     r = kref.group_aggregate(jnp.asarray(keys), jnp.asarray(vals), 128)
-    claimed = np.asarray(cnt[:, 0]) > 0
+    claimed = np.asarray(cnt) > 0
     np.testing.assert_array_equal(np.asarray(s), np.asarray(r["sum"]))
     np.testing.assert_array_equal(np.asarray(mn)[claimed],
                                   np.asarray(r["min"])[claimed])

@@ -105,7 +105,7 @@ class TestCheckpoint:
         """Saved unsharded -> restored with explicit shardings (new mesh)."""
         from repro.checkpoint.manager import CheckpointManager
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.jax_compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((1,), ("model",))
         with tempfile.TemporaryDirectory() as d:
             cm = CheckpointManager(d)
