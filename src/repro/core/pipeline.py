@@ -61,6 +61,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import operators as op_ir
 from repro.core import pool as fpool
@@ -169,13 +170,17 @@ class PipelineResult:
                     # is encrypted: then its tail is keystream, not zeros.
                     w, n = self._rows.shape
                     ship = n if self._meta.get("sealed") else self._count
-                    rows = np.zeros((n, w), np.float32)
-                    rows[:ship] = np.asarray(self._rows[:, :ship]).T
+                    with TraceAnnotation("fv.d2h"):
+                        cols = np.asarray(self._rows[:, :ship])
+                    with TraceAnnotation("fv.layout"):
+                        rows = np.zeros((n, w), np.float32)
+                        rows[:ship] = cols.T
                     self._rows = rows
                 self._shipped = int(raw["shipped"])
                 if "ids" in raw:
-                    self._ids = np.rint(np.asarray(
-                        raw["ids"][: self._count])).astype(np.int64)
+                    with TraceAnnotation("fv.d2h"):
+                        ids = np.asarray(raw["ids"][: self._count])
+                    self._ids = np.rint(ids).astype(np.int64)
             elif self.kind == "mask":
                 self._mask = raw["mask"]
                 self._shipped = int(raw["shipped"])
@@ -194,14 +199,16 @@ class PipelineResult:
         # only the `ovf_count` collision rows cross to the host — never the
         # partition-sized key/value arrays.
         n_ovf = int(raw["ovf_count"])
+        with TraceAnnotation("fv.d2h"):
+            ovf_keys = np.asarray(raw["ovf_keys"][:n_ovf])
+            ovf_vals = (np.asarray(raw["ovf_vals"][:, :n_ovf]).T
+                        if self._meta.get("cm")
+                        else np.asarray(raw["ovf_vals"][:n_ovf]))
         self._groups = dict(
             bucket_keys=raw["bucket_keys"], count=raw["count"],
             sum=raw["sum"], min=raw["min"], max=raw["max"],
             drop_key=self._meta.get("drop_key"),
-            ovf_keys=np.asarray(raw["ovf_keys"][:n_ovf]),
-            ovf_vals=(np.asarray(raw["ovf_vals"][:, :n_ovf]).T
-                      if self._meta.get("cm")
-                      else np.asarray(raw["ovf_vals"][:n_ovf])))
+            ovf_keys=ovf_keys, ovf_vals=ovf_vals)
         self._shipped = int(raw["shipped"])
 
 
@@ -743,14 +750,16 @@ class CompiledPipeline:
         # the front IN the traced program (stable two-way partition via the
         # composite-key sort), so the response ships B buckets + the actual
         # collision rows — the host never touches partition-sized arrays
-        order, _ = kref.sort_by_bucket((~keep).astype(jnp.int32), 2)
+        with jax.named_scope("fv.ovf_pack"):
+            order, _ = kref.sort_by_bucket((~keep).astype(jnp.int32), 2)
+            ovf_keys = keys[order]
+            ovf_vals = (kref.take_lanes(vals, order) if self._cm
+                        else vals[order])
         shipped = (np.int32(nb * (2 + 4 * len(vcols)) * WORD_BYTES)
                    + keep_cnt * np.int32((1 + len(vcols)) * WORD_BYTES))
         return {"bucket_keys": res["bucket_keys"], "count": res["count"],
                 "sum": res["sum"], "min": res["min"], "max": res["max"],
-                "ovf_keys": keys[order],
-                "ovf_vals": (kref.take_lanes(vals, order) if self._cm
-                             else vals[order]),
+                "ovf_keys": ovf_keys, "ovf_vals": ovf_vals,
                 "ovf_count": keep_cnt, "shipped": shipped}
 
 

@@ -143,16 +143,18 @@ def group_aggregate(keys: jnp.ndarray, values_t: jnp.ndarray, *,
     assert n % block_rows == 0 and v % 8 == 0, (n, v)
     assert n_buckets & (n_buckets - 1) == 0, "n_buckets must be a power of 2"
 
-    # --- sort by bucket + global first-claim ownership (pure XLA) ----------
-    bucket = ref.bucket_of(keys, n_buckets)
-    order, sb = ref.sort_by_bucket(bucket, n_buckets)
-    start, _end, nonempty = ref.segment_spans(sb, n_buckets)
-    claimed = jnp.where(nonempty, keys[order[start]], _SENT)
-    owns = keys == claimed[bucket]
+    # --- sort by bucket + global first-claim ownership, the stream put in
+    # bucket order (pure XLA) ----------------------------------------------
+    with jax.named_scope("fv.bucket_sort"):
+        bucket = ref.bucket_of(keys, n_buckets)
+        order, sb = ref.sort_by_bucket(bucket, n_buckets)
+        start, _end, nonempty = ref.segment_spans(sb, n_buckets)
+        claimed = jnp.where(nonempty, keys[order[start]], _SENT)
+        owns = keys == claimed[bucket]
+        sv = ref.take_lanes(values_t, order)
+        so = owns[order].astype(jnp.int32)
 
     # --- grid shape: P partials x G blocks each, P <= MAX_PARTIALS ---------
-    sv = ref.take_lanes(values_t, order)
-    so = owns[order].astype(jnp.int32)
     nb_total = n // block_rows
     p = min(nb_total, MAX_PARTIALS)
     g = -(-nb_total // p)

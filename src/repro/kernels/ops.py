@@ -75,13 +75,15 @@ def select_project_cols(table_t, sel_ops, sel_vals, proj_mask, n_valid=None,
                                           interpret=interpret)
     # --- stitch blocks (the paper's length-prefixed response packets) ------
     # output row p comes from the first block whose running end exceeds p
-    ends = jnp.cumsum(counts)
-    total = ends[-1]
-    p = jnp.arange(n, dtype=jnp.int32)
-    blk = jnp.minimum(jnp.searchsorted(ends, p, side="right"),
-                      counts.shape[0] - 1).astype(jnp.int32)
-    src = blk * block_rows + p - (ends[blk] - counts[blk])
-    return jnp.where(p < total, ref.take_lanes(packed_b[:a], src), 0.0), total
+    with jax.named_scope("fv.stitch"):
+        ends = jnp.cumsum(counts)
+        total = ends[-1]
+        p = jnp.arange(n, dtype=jnp.int32)
+        blk = jnp.minimum(jnp.searchsorted(ends, p, side="right"),
+                          counts.shape[0] - 1).astype(jnp.int32)
+        src = blk * block_rows + p - (ends[blk] - counts[blk])
+        out = jnp.where(p < total, ref.take_lanes(packed_b[:a], src), 0.0)
+    return out, total
 
 
 # ---------------------------------------------------------------------------

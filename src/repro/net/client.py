@@ -35,6 +35,7 @@ import threading
 import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import client as fv
 from repro.core import operators as op_ir
@@ -82,19 +83,20 @@ class RemotePending:
         self.error: Exception | None = None
 
     def _attach(self, payload: dict) -> None:
-        rows = payload.get("rows")
-        if "n_rows" in payload:         # survivors only: restore the tail
-            full = np.zeros((int(payload["n_rows"]),) + rows.shape[1:],
-                            rows.dtype)
-            full[: rows.shape[0]] = rows
-            rows = full
-        res = PipelineResult(
-            payload["kind"], rows=rows,
-            count=payload.get("count"), groups=payload.get("groups"),
-            mask=payload.get("mask"),
-            shipped_bytes=int(payload.get("shipped", 0)),
-            read_bytes=int(payload.get("read", 0)),
-            sel_ids=payload.get("sel_ids"))
+        with TraceAnnotation("fv.attach"):
+            rows = payload.get("rows")
+            if "n_rows" in payload:     # survivors only: restore the tail
+                full = np.zeros((int(payload["n_rows"]),) + rows.shape[1:],
+                                rows.dtype)
+                full[: rows.shape[0]] = rows
+                rows = full
+            res = PipelineResult(
+                payload["kind"], rows=rows,
+                count=payload.get("count"), groups=payload.get("groups"),
+                mask=payload.get("mask"),
+                shipped_bytes=int(payload.get("shipped", 0)),
+                read_bytes=int(payload.get("read", 0)),
+                sel_ids=payload.get("sel_ids"))
         self.result = res
         self.qp.requests += 1
         self.qp._bytes_shipped += int(payload.get("shipped", 0))
@@ -207,7 +209,7 @@ class RemotePool:
     @property
     def stats(self) -> PoolStats:
         try:
-            raw = self._node._call(wire.STATS, {}, op="stats")
+            raw = self._node.server_stats()
         except fv.NodeDeadError:
             return self._last_stats      # last observation of a dead node
         self._last_stats = PoolStats(
@@ -375,15 +377,20 @@ class RemoteNodeHandle:
         try:
             ftype, req_id, length = wire.parse_header(
                 hdr, max_payload=self.max_payload)
-            body = self._recv_exact(length, op=op) if length else b""
-            trailer = self._recv_exact(wire.TRAILER_SIZE, op=op)
+            with TraceAnnotation("fv.recv"):
+                body = self._recv_exact(length, op=op) if length else b""
+                trailer = self._recv_exact(wire.TRAILER_SIZE, op=op)
             # integrity before trust: a corrupted frame fails typed here
             # and POISONS the stream (no resync point exists) — the node
             # reads as dead and failover reroutes, never wrong bytes
-            wire.check_crc(hdr, body, trailer)
+            with TraceAnnotation("fv.crc"):
+                wire.check_crc(hdr, body, trailer)
         except wire.ProtocolError as e:
             raise self._die(op) from e
-        return ftype, req_id, (wire.decode_value(body) if length else None)
+        if not length:
+            return ftype, req_id, None
+        with TraceAnnotation("fv.decode"):
+            return ftype, req_id, wire.decode_value(body)
 
     def _absorb(self, ftype: int, req_id: int, payload) -> None:
         """Route a response frame to its in-flight verb."""
@@ -440,9 +447,15 @@ class RemoteNodeHandle:
     @property
     def dispatches(self) -> int:
         try:
-            return int(self._call(wire.STATS, {}, op="stats")["dispatches"])
+            return int(self.server_stats()["dispatches"])
         except fv.NodeDeadError:
             return 0
+
+    def server_stats(self) -> dict:
+        """The server's `STATS` reply: pool counters, dispatches, queue
+        depth, sheds, and the queue wait of the requests it has picked
+        (`queue_wait_s` summed over `queued`)."""
+        return self._call(wire.STATS, {}, op="stats")
 
     def open_connection(self) -> RemoteQPair:
         resp = self._call(wire.OPEN_QP, {}, op="open_connection")

@@ -49,6 +49,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import compile_cache
 from repro.core import client as fv
@@ -110,6 +111,7 @@ class _Submit:
     deadline: float | None = None   # time.monotonic() expiry from the
     #                                 frame's deadline_ms budget; checked
     #                                 again right before dispatch
+    admitted: float = 0.0           # time.monotonic() at admission
 
 
 class _Conn:
@@ -162,6 +164,10 @@ class FViewServer:
         self._inflight_total = 0
         self._shed_total = 0
         self._deadline_shed_total = 0
+        # admission-to-pick wait of the requests `_run_batch` picked
+        # (worker thread only, like `_stats_payload` that reports them)
+        self._queue_wait_s = 0.0
+        self._queued_total = 0
         self._closing = False
         self._server: asyncio.AbstractServer | None = None
         self._loop: asyncio.AbstractEventLoop | None = None
@@ -358,8 +364,10 @@ class FViewServer:
         for ent in batch:
             if ent.error is not None:
                 continue
-            if (ent.deadline is not None
-                    and time.monotonic() >= ent.deadline):
+            now = time.monotonic()
+            self._queue_wait_s += now - ent.admitted
+            self._queued_total += 1
+            if ent.deadline is not None and now >= ent.deadline:
                 # budget spent while queued behind the batching window:
                 # shed BEFORE dispatch — an expired request never
                 # half-runs (and never costs a scheduler round)
@@ -415,8 +423,9 @@ class FViewServer:
     # ----------------------------------------------------------- connection
     async def _send(self, conn: _Conn, ftype: int, req_id: int,
                     obj=None) -> None:
-        data = wire.encode_frame(ftype, req_id, obj,
-                                 max_payload=self.max_payload)
+        with TraceAnnotation("srv.encode"):
+            data = wire.encode_frame(ftype, req_id, obj,
+                                     max_payload=self.max_payload)
         async with conn.wlock:
             conn.writer.write(data)
             try:
@@ -615,7 +624,7 @@ class FViewServer:
             row_ids=None if row_ids is None
             else np.asarray(row_ids, np.int32),
             done=self._loop.create_future(),
-            deadline=deadline)
+            deadline=deadline, admitted=time.monotonic())
         conn.entries[req_id] = ent
         conn.queue.append(ent)
         self._inflight_total += 1
@@ -632,6 +641,8 @@ class FViewServer:
                 "inflight": self._inflight_total,
                 "shed": self._shed_total,
                 "deadline_shed": self._deadline_shed_total,
+                "queue_wait_s": self._queue_wait_s,
+                "queued": self._queued_total,
                 "conns": len(self._conns)}
 
     def _pool_verb(self, ftype: int, payload):

@@ -252,7 +252,10 @@ class TestDeadlines:
 
     def test_budget_spent_in_server_queue_sheds_pre_dispatch(self, data):
         # a wide batching window guarantees the 50 ms budget dies in
-        # the server queue — the shed happens at dispatch pick, typed
+        # the server queue — the shed happens at dispatch pick, typed.
+        # The FLUSH goes only after the window has run: a FLUSH that
+        # reaches the server before the drain wakes marks the drain
+        # urgent, and the drain then skips the window
         servers = tn.spawn_servers(1, flush_interval_s=0.3)
         try:
             node = RemoteNodeHandle("127.0.0.1", servers[0].port,
@@ -263,8 +266,13 @@ class TestDeadlines:
             node.pool.write_table(ft, tn.schema().encode(data))
             pend = node.submit(qp, ft, (op.Select(
                 (op.Predicate("c1", "<", 0.0),)),), deadline_s=0.05)
+            time.sleep(0.6)
             with pytest.raises(DeadlineExceededError, match="queue"):
                 pend.wait()
+            stats = node.server_stats()
+            assert stats["deadline_shed"] == 1
+            assert stats["queued"] == 1
+            assert stats["queue_wait_s"] >= 0.05
         finally:
             _teardown(None, (), servers)
 

@@ -444,6 +444,31 @@ class TestBackpressure:
         finally:
             servers[0].stop()
 
+    def test_stats_reply_carries_queue_wait(self, data):
+        """`STATS` reports each picked request's admission-to-pick wait:
+        with the FLUSH sent after the batching window has run, the one
+        request waited the whole window."""
+        servers = spawn_servers(1, flush_interval_s=0.1)
+        try:
+            node = RemoteNodeHandle("127.0.0.1", servers[0].port,
+                                    node_id=0)
+            stats = node.server_stats()
+            assert stats["queued"] == 0 and stats["queue_wait_s"] == 0.0
+            qp = node.open_connection()
+            ft = schema()
+            node.pool.alloc_table(ft)
+            node.pool.write_table(ft, schema().encode(data))
+            pend = node.submit(qp, ft, (op.Select(
+                (op.Predicate("c1", "<", 0.0),)),))
+            time.sleep(0.3)
+            pend.wait()
+            stats = node.server_stats()
+            assert stats["queued"] == 1
+            assert stats["queue_wait_s"] >= 0.09
+            node.close()
+        finally:
+            servers[0].stop()
+
 
 # ------------------------------------------- robustness against a live server
 class TestLiveProtocolRobustness:
