@@ -54,7 +54,10 @@ def select_project_cols(table_t, sel_ops, sel_vals, proj_mask, n_valid=None,
     """Column-major select_project: table_t (A, N) f32, one row per lane.
 
     Rows at or past `n_valid` (optional traced scalar) never survive.
-    Returns (packed_t (A, N) f32 globally compacted, count scalar i32).
+    Returns (packed_t (A, N) f32 globally compacted, count scalar i32): the
+    kernel writes the survivors back to back as it streams the blocks (the
+    paper's length-prefixed response packets, sent in order), so lanes
+    [0, count) hold them in row order and every lane past them is zero.
     """
     if interpret is None:
         interpret = _interpret_default()
@@ -69,21 +72,11 @@ def select_project_cols(table_t, sel_ops, sel_vals, proj_mask, n_valid=None,
     proj2 = _pad_to(jnp.asarray(proj_mask, jnp.float32)[:, None], 0, 8)
     limit = (jnp.int32(n) if n_valid is None
              else jnp.minimum(jnp.asarray(n_valid, jnp.int32), n))
-    packed_b, counts = _sp.select_project(t, ops2, vals2, proj2,
-                                          limit.reshape(1, 1),
-                                          block_rows=block_rows,
-                                          interpret=interpret)
-    # --- stitch blocks (the paper's length-prefixed response packets) ------
-    # output row p comes from the first block whose running end exceeds p
-    with jax.named_scope("fv.stitch"):
-        ends = jnp.cumsum(counts)
-        total = ends[-1]
-        p = jnp.arange(n, dtype=jnp.int32)
-        blk = jnp.minimum(jnp.searchsorted(ends, p, side="right"),
-                          counts.shape[0] - 1).astype(jnp.int32)
-        src = blk * block_rows + p - (ends[blk] - counts[blk])
-        out = jnp.where(p < total, ref.take_lanes(packed_b[:a], src), 0.0)
-    return out, total
+    packed, total = _sp.select_project(t, ops2, vals2, proj2,
+                                       limit.reshape(1, 1),
+                                       block_rows=block_rows,
+                                       interpret=interpret)
+    return packed[:a, :n], total
 
 
 # ---------------------------------------------------------------------------

@@ -4,9 +4,10 @@ XLA-native lowering, on the same pool pages.
 The kernel lowering is what runs on the TPU: Pallas kernels over a
 column-major (A, n) work table. Off the TPU its kernels run in Pallas
 interpret mode, so every verb's traced program — column reads, the
-column-major cipher, the join/id/validity columns, the block stitch and
-the host-side row layout of the response — is checked here against the
-XLA-native lowering, which tests/test_fused_path.py holds to kernels/ref.py.
+column-major cipher, the join/id/validity columns, the select kernel's
+global compaction and the host-side row layout of the response — is
+checked here against the XLA-native lowering, which
+tests/test_fused_path.py holds to kernels/ref.py.
 """
 import numpy as np
 import jax.numpy as jnp

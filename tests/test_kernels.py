@@ -96,6 +96,56 @@ def test_select_project_stability(rng):
     assert np.all(np.diff(tags) > 0), "pack must preserve row order"
 
 
+# The kernel carries a write offset across its grid and DMAs each full block
+# of survivors to the next output block, through a ring of blocks in flight
+# (`select_project._RING`): the cases straddle blocks, outrun the ring, cut
+# rows mid-block or at a block's edge, and pass the tail pad.
+R = 256
+CARRIED = {
+    # name: (rows, words, threshold on word 0 or None for no predicate,
+    #        words projected away, n_valid)
+    "none": (700, 8, -1e9, (), None),
+    "all": (12 * R + 1, 8, None, (), None),
+    "all_whole_blocks": (9 * R, 8, None, (), None),
+    "straddle": (13 * R + 100, 8, 0.7, (), None),
+    "n_valid_mid_block": (1500, 8, 0.5, (), 3 * R + 232),
+    "projection": (900, 8, 0.4, (0, 3, 5), None),
+    "nan_inf_neg_zero": (800, 8, 0.6, (), None),
+    "ids_column_17": (1200, 17, 0.3, (), None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CARRIED))
+def test_select_project_carried_compaction(rng, name):
+    """Global compaction in the kernel: survivors bit-identical to
+    ref.select_project in lanes [0, count), every word past them zero."""
+    n, a, thr, dropped, n_valid = CARRIED[name]
+    table = rng.uniform(size=(n, a)).astype(np.float32)
+    if name == "nan_inf_neg_zero":
+        special = np.array([np.nan, np.inf, -np.inf, -0.0], np.float32)
+        table[:, 1:] = rng.choice(special, size=(n, a - 1))
+    sel_ops = np.zeros(a, np.int32)
+    sel_vals = np.zeros(a, np.float32)
+    if thr is not None:
+        sel_ops[0] = kref.OP_LT
+        sel_vals[0] = thr
+    proj = np.ones(a, np.float32)
+    proj[list(dropped)] = 0
+    packed_t, count = kops.select_project_cols(
+        jnp.asarray(table.T), jnp.asarray(sel_ops), jnp.asarray(sel_vals),
+        jnp.asarray(proj), None if n_valid is None else jnp.int32(n_valid))
+    live = n if n_valid is None else n_valid
+    rp, rc = kref.select_project(
+        jnp.asarray(table[:live]), jnp.asarray(sel_ops),
+        jnp.asarray(sel_vals), jnp.asarray(proj))
+    got = np.asarray(packed_t).view(np.int32)
+    assert got.shape == (a, n)
+    assert int(count) == int(rc)
+    np.testing.assert_array_equal(got[:, :int(count)],
+                                  np.asarray(rp)[:int(rc)].T.view(np.int32))
+    assert not got[:, int(count):].any(), "words past count must be zero"
+
+
 # ---------------------------------------------------------------------------
 # hash_group
 # ---------------------------------------------------------------------------

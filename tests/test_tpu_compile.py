@@ -100,18 +100,35 @@ PIPES = {
 }
 
 
-@pytest.mark.parametrize("stacked", [False, True], ids=["solo", "stacked"])
-@pytest.mark.parametrize("name", sorted(PIPES))
-def test_fused_executable_compiles_for_v5e(one_chip, native, name, stacked):
-    """`_jit_pages` on the kernel lowering: one request, or a stacked
-    4-client round, straight from the pool buffer."""
+def _compile_pipe(sharding, name, stacked=False):
     schema = FTable("t", (Column("c0", "i32"),)
                     + tuple(Column(f"c{i}") for i in range(1, W)), n_rows=N)
     pipe = CompiledPipeline(schema, PIPES[name], interpret=False)
     n_pages = -(-N * W // PAGE_WORDS)
     lead = (4,) if stacked else ()
-    _compile(pipe._jit_pages,
-             _spec(one_chip, (POOL_PAGES + 1, PAGE_WORDS), jnp.float32),
-             _spec(one_chip, lead + (n_pages,), jnp.int32),
-             _spec(one_chip, lead, jnp.int32), None, None, None,
-             n_rows=N, row_words=W, page_words=None)
+    return _compile(pipe._jit_pages,
+                    _spec(sharding, (POOL_PAGES + 1, PAGE_WORDS),
+                          jnp.float32),
+                    _spec(sharding, lead + (n_pages,), jnp.int32),
+                    _spec(sharding, lead, jnp.int32), None, None, None,
+                    n_rows=N, row_words=W, page_words=None)
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["solo", "stacked"])
+@pytest.mark.parametrize("name", sorted(PIPES))
+def test_fused_executable_compiles_for_v5e(one_chip, native, name, stacked):
+    """`_jit_pages` on the kernel lowering: one request, or a stacked
+    4-client round, straight from the pool buffer."""
+    _compile_pipe(one_chip, name, stacked)
+
+
+@pytest.mark.parametrize("name", ["pre_decrypt", "select", "smart_address"])
+def test_fused_select_compacts_in_the_kernel(one_chip, native, name):
+    """A select's survivors leave the select kernel globally compacted: its
+    executable holds no loop and no gather, which a stitch of block-local
+    survivors would need (a search over the blocks' ends, a gather per
+    column over every row). The table is one pool page here, so the
+    pool's page copy-out adds no loop either."""
+    text = _compile_pipe(one_chip, name).as_text()
+    assert " while(" not in text
+    assert " gather(" not in text

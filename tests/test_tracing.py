@@ -5,12 +5,12 @@ largest device stretches.
 Host spans: `fv.d2h` and `fv.layout` in `PipelineResult.finalize`,
 `srv.encode` in the server's frame send, `fv.recv`, `fv.crc`, `fv.decode`
 in the client's frame read, `fv.attach` where the client rebuilds a
-result. Device scopes: `fv.stitch` (the select's block stitch),
-`fv.bucket_sort` (the group kernel's bucket sort and ownership, and the
-stream put in bucket order), `fv.ovf_pack` (the group's overflow
-compaction). Each is checked where it lands: the spans in a profiler
-trace, the scopes in the compiled program's op names, which a device
-trace carries as each op's name path.
+result. Device scopes: `fv.bucket_sort` (the group kernel's bucket sort
+and ownership, and the stream put in bucket order), `fv.ovf_pack` (the
+group's overflow compaction); a select carries none, its compaction
+being all in the select kernel. Each is checked where it lands: the
+spans in a profiler trace, the scopes in the compiled program's op
+names, which a device trace carries as each op's name path.
 """
 import numpy as np
 import jax
@@ -58,7 +58,7 @@ def _spans(tmp_path, fn) -> dict:
 
 
 @pytest.mark.parametrize("pipeline, interpret, scopes", [
-    ((op.Project(("c1", "c4")), SEL), False, ("fv.stitch",)),
+    ((op.Project(("c1", "c4")), SEL), False, ()),
     (GROUP, False, ("fv.bucket_sort", "fv.ovf_pack")),
     (GROUP, True, ("fv.ovf_pack",)),
 ], ids=["select-kernels", "group-kernels", "group-xla"])
@@ -69,9 +69,10 @@ def test_device_scopes_name_the_compiled_ops(pipeline, interpret, scopes):
                                 None).compile().as_text()
     for scope in scopes:
         assert f"/{scope}/" in text, scope
-    # the select's stitch is the only scope a select path carries
-    if "fv.stitch" not in scopes:
-        assert "fv.stitch" not in text
+    # no path carries a scope it does not name (a select none: its
+    # compaction is all in the select kernel)
+    for scope in {"fv.stitch", "fv.bucket_sort", "fv.ovf_pack"} - set(scopes):
+        assert f"/{scope}/" not in text, scope
 
 
 def test_finalize_spans_the_copy_and_the_row_layout(tmp_path):
