@@ -17,9 +17,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from fvb import data as fdata  # noqa: E402
 from fvb import harness  # noqa: E402
-from fvb import reference as ref  # noqa: E402
 from fvb import spec as fspec  # noqa: E402
 
 
@@ -32,22 +30,24 @@ def main(argv=None) -> int:
     spec = fspec.load(args.workload)
     rows = harness.REHEARSAL_ROWS if args.rehearse else int(
         spec.config["rows"])
+    limits = spec.limits
     failed_all = True
     for seed in (int(s) for s in args.seeds.split(",")):
         t0 = time.perf_counter()
-        table = fdata.make_table(spec.config, seed, rows)
+        table = spec.table_kind.make_table(spec.config, seed, rows)
         nums: dict = {}
         for inst in spec.traffic["instances"]:
-            want = ref.expect(table.words, table.index, inst)
-            got = ref.control_answer(table.words, table.index, inst)
-            for k, v in ref.compare(got, want).items():
+            verb = spec.verbs[inst["verb"]]
+            want = verb.expect(table.words, table.index, inst)
+            got = verb.control(table.words, table.index, inst)
+            for k, v in verb.compare(got, want).items():
                 nums[k] = nums.get(k, 0) + v
-        fails = any(v > ref.LIMITS[k] for k, v in nums.items())
+        fails = any(v > limits[k] for k, v in nums.items())
         failed_all &= fails
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "rows": rows, "control_fails": fails,
                           "numbers": {k: {"value": v,
-                                          "limit": ref.LIMITS[k]}
+                                          "limit": limits[k]}
                                       for k, v in nums.items()},
                           "seconds": time.perf_counter() - t0}), flush=True)
     return 0 if failed_all else 1

@@ -3,18 +3,20 @@
     python3 perfbench/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1> [--rehearse]
 
-Set-up makes the table from the seed, stands up the served path (an
-in-process `FViewServer` and a `RemoteNodeHandle` over localhost), writes
-the table and the pool's other tables into the node's pool on the
-server's side (loading is set-up, not traffic), and sends every instance
-of the mix once, which compiles or loads each executable and warms the
-client's merge. The window then drives `farview_request(...).finalize()`
-(and, for a group-by, the client's `merge_group_partials`) in a closed
-loop with one client, whole rounds of the mix until `--seconds` have
-passed; it closes at the last answer. The bytes each query brings the
-client are counted at its sockets (`served.WireCount`), and the device
-memory in use is sampled while the window runs. A sample of the answers,
-drawn from the seed, is compared with the plain reference once the
+Set-up makes the table from the seed (its table kind,
+`tables/<kind>.py`), stands up the served path (an in-process
+`FViewServer` and a `RemoteNodeHandle` over localhost), writes the table
+and the pool's other tables into the node's pool on the server's side
+(loading is set-up, not traffic), and sends every instance of the mix
+once, which compiles or loads each executable and warms the client's
+side. The window then drives `farview_request(...).finalize()` and the
+instance's verb's `answer` (`verbs/<verb>.py`: what the client holds once
+the result is final) in a closed loop with one client, whole rounds of
+the mix until `--seconds` have passed; it closes at the last answer. The
+bytes each query brings the client are counted at its sockets
+(`served.WireCount`), and the device memory in use is sampled while the
+window runs. A sample of the answers, drawn from the seed (`KEEP` of each
+instance's verb), is compared with the verb's plain reference once the
 window has closed and the program is stopped.
 
 The last line of standard output is one JSON object: `correct`,
@@ -44,8 +46,6 @@ from pathlib import Path
 import numpy as np
 
 from fvb import data as fdata
-from fvb import peaks as fpeaks
-from fvb import reference as ref
 from fvb import spec as fspec
 from fvb import traffic as ftraffic
 from fvb import tracefold
@@ -53,7 +53,6 @@ from fvb.tracefold import WINDOW_SPAN
 
 REHEARSAL_ROWS = 4096
 REHEARSAL_POOL = 8 << 21            # 8 pages of 2 MiB
-KEEP_ROWS_ANSWERS = 2               # retained select answers per instance
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 MEMORY_PERIOD_S = 0.02              # device memory sampled this often
 
@@ -70,7 +69,7 @@ class Query:
     resp_bytes: int                 # received at the client's sockets
     shipped: int
     read: int
-    count: int                      # survivors, or overflow rows of a group
+    count: int                      # the verb's: survivors, overflow rows
 
     @property
     def latency(self) -> float:
@@ -81,7 +80,7 @@ class Query:
 class Run:
     """What a metric reader reads."""
     spec: fspec.Spec
-    insts: dict                     # name -> instance spec
+    insts: dict                     # name -> traffic.Instance
     n_rows: int
     width: int
     setup_s: float = 0.0
@@ -241,8 +240,8 @@ def _run(args, spec: fspec.Spec, t0: float) -> int:
     cfg = spec.config
     n_rows = REHEARSAL_ROWS if args.rehearse else int(cfg["rows"])
     pool_bytes = REHEARSAL_POOL if args.rehearse else int(cfg["pool_bytes"])
-    insts = ftraffic.instances(spec.traffic, cfg)
-    run = Run(spec=spec, insts={i.name: i.spec for i in insts},
+    insts = ftraffic.instances(spec)
+    run = Run(spec=spec, insts={i.name: i for i in insts},
               n_rows=n_rows, width=len(cfg["columns"]),
               device_kind=device["kind"])
 
@@ -255,7 +254,7 @@ def _run(args, spec: fspec.Spec, t0: float) -> int:
 
     # ------------------------------------------------------------- set-up
     t_data = time.perf_counter()
-    table = fdata.make_table(cfg, args.seed, n_rows)
+    table = spec.table_kind.make_table(cfg, args.seed, n_rows)
     at_rest = cfg.get("encrypted_at_rest")
     stored = fdata.encrypt(table.words, at_rest) if at_rest else table.words
     cols = tuple(Column(c["name"], c["dtype"]) for c in cfg["columns"])
@@ -278,7 +277,8 @@ def _run(args, spec: fspec.Spec, t0: float) -> int:
             other = fv.alloc_table_mem(
                 qp, FTable(f"{cfg['name']}.{t}", cols, n_rows=n_rows))
             server.node.pool.write_table(
-                other, fdata.other_table(cfg, args.seed, t, n_rows))
+                other, spec.table_kind.other_table(cfg, args.seed, t,
+                                                   n_rows))
             jax.block_until_ready(server.node.pool.buf)
         log(f"table and server ready in {t_pool - t_data:.3f}s; pool written in "
             f"{time.perf_counter() - t_pool:.3f}s: {cfg['tables']} tables, "
@@ -290,14 +290,7 @@ def _run(args, spec: fspec.Spec, t0: float) -> int:
             res = fv.farview_request(qp, ft, inst.pipeline)
             with TraceAnnotation("fv.finalize"):
                 res = res.finalize()
-            if inst.is_group:
-                count = len(res.groups["ovf_keys"])
-                with TraceAnnotation("fv.merge"):
-                    answer = fv.merge_group_partials(
-                        ft, inst.pipeline, [res]).groups
-            else:
-                count = int(res.count)
-                answer = (count, res.rows)
+            answer, count = inst.verb.answer(res, ft, inst.pipeline)
             q1 = time.perf_counter()
             conns1, b1 = wire.read()
             if conns1 != conns:
@@ -305,7 +298,7 @@ def _run(args, spec: fspec.Spec, t0: float) -> int:
                                    "changed during a query")
             q = Query(inst.name, q0, q1, b1 - b0, int(res.shipped_bytes),
                       int(res.read_bytes), count)
-            if q.resp_bytes < fpeaks.answer_bytes(
+            if q.resp_bytes < inst.verb.answer_bytes(
                     inst.spec, int(cfg["word_bytes"]), run.width, q.count):
                 short[0] += 1
             return answer, q
@@ -338,8 +331,7 @@ def _run(args, spec: fspec.Spec, t0: float) -> int:
                             log(f"query {inst.name} failed: {e!r}")
                             raise
                         run.queries.append(q)
-                        sample.offer(q.inst, answer, None if inst.is_group
-                                     else KEEP_ROWS_ANSWERS)
+                        sample.offer(q.inst, answer, inst.verb.KEEP)
                         log(f"q {q.inst}: {q.latency:.4f}s "
                             f"{q.resp_bytes} B")
                 window_s = time.perf_counter() - w0
@@ -363,13 +355,14 @@ def _run(args, spec: fspec.Spec, t0: float) -> int:
 
     # -------------------------------------------------- after the window
     for name, answers in sample.kept.items():
-        want = ref.expect(table.words, table.index, run.insts[name])
+        verb = run.insts[name].verb
+        want = verb.expect(table.words, table.index, run.insts[name].spec)
         for ans in answers:
-            for k, v in ref.compare(ans, want).items():
+            for k, v in verb.compare(ans, want).items():
                 checks[k] = checks.get(k, 0) + v
     del sample
     checks["resp_below_answer"] = short[0]
-    limits = dict(ref.LIMITS, pool_off_cipher=0, resp_below_answer=0)
+    limits = dict(spec.limits, pool_off_cipher=0, resp_below_answer=0)
     answered = {q.inst for q in run.queries}
     correct = (run.failed == 0 and answered == set(run.insts)
                and all(v <= limits[k] for k, v in checks.items()))

@@ -1,13 +1,8 @@
 """The one generator of traffic: a mix file's instances, as the program's
 operator pipelines, in an order drawn from the seed.
 
-An instance is plain data:
-
-    {"name": "S25P",
-     "project": [cols] | "smart": [cols],       (at most one of the two)
-     "select": [[col, op, value], ...],         (AND of predicates)
-     "group": {"key": col, "values": [cols], "aggs": [...], "n_buckets": n}}
-
+An instance is plain data: its `name`, its `verb` (the module
+`verbs/<verb>.py`, which reads the instance's other keys) and those keys.
 A configuration held encrypted at rest puts `Crypt(pre)` with its key in
 front of every instance. The loop is closed with one client: the next
 query goes out when the previous answer is final. Every round issues each
@@ -17,8 +12,10 @@ weighs the same in every run whatever the window holds.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from types import ModuleType
 
 from fvb.data import rng_of
+from fvb.spec import Spec
 
 
 @dataclass(frozen=True)
@@ -26,37 +23,27 @@ class Instance:
     name: str
     spec: dict
     pipeline: tuple         # the program's operator IR
-
-    @property
-    def is_group(self) -> bool:
-        return "group" in self.spec
+    verb: ModuleType        # verbs/<spec["verb"]>.py
 
 
-def pipeline_of(spec: dict, config: dict) -> tuple:
+def pipeline_of(spec: dict, config: dict, verb: ModuleType) -> tuple:
     from repro.core import operators as op
     ops = []
     at_rest = config.get("encrypted_at_rest")
     if at_rest:
         ops.append(op.Crypt(key=tuple(at_rest["key"]),
                             nonce=int(at_rest["nonce"]), when="pre"))
-    if "project" in spec:
-        ops.append(op.Project(tuple(spec["project"])))
-    if "smart" in spec:
-        ops.append(op.SmartAddress(tuple(spec["smart"])))
-    if spec.get("select"):
-        ops.append(op.Select(tuple(op.Predicate(c, o, float(v))
-                                   for c, o, v in spec["select"])))
-    if "group" in spec:
-        g = spec["group"]
-        ops.append(op.GroupBy(g["key"], tuple(g["values"]),
-                              aggs=tuple(g["aggs"]),
-                              n_buckets=int(g["n_buckets"])))
+    ops.extend(verb.pipeline(spec, config))
     return op.validate_pipeline(tuple(ops))
 
 
-def instances(traffic: dict, config: dict) -> list[Instance]:
-    return [Instance(s["name"], s, pipeline_of(s, config))
-            for s in traffic["instances"]]
+def instances(spec: Spec) -> list[Instance]:
+    out = []
+    for s in spec.traffic["instances"]:
+        verb = spec.verbs[s["verb"]]
+        out.append(Instance(s["name"], s, pipeline_of(s, spec.config, verb),
+                            verb))
+    return out
 
 
 def rounds(insts: list[Instance], seed: int):

@@ -1,6 +1,7 @@
 """The fused executable's share of its roofline, in percent: the least
-time its queries need (the bytes their semantics require, fvb/peaks.py,
-over the chip's peak HBM bandwidth) over its measured device time."""
+time its queries need (the bytes their semantics require, each query's
+verb's `query_bytes`, over the chip's peak HBM bandwidth, fvb/peaks.py)
+over its measured device time."""
 from fvb import peaks
 
 
@@ -12,10 +13,7 @@ def read(run):
     wb = run.spec.config["word_bytes"]
     need = 0
     for q in run.queries:
-        spec = run.insts[q.inst]
-        grouped = "group" in spec
-        need += peaks.query_bytes(
-            spec, wb, run.n_rows, run.width,
-            count=0 if grouped else q.count,
-            n_overflow=q.count if grouped else 0)
+        inst = run.insts[q.inst]
+        need += inst.verb.query_bytes(inst.spec, wb, run.n_rows, run.width,
+                                      q.count)
     return 100.0 * (need / bw) / f.exec_s

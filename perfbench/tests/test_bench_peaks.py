@@ -1,5 +1,6 @@
 """The peaks table, the least-bytes count behind `exec_roofline`, and the
-least bytes an answer carries over the wire."""
+least bytes an answer carries over the wire (each verb's `query_bytes`
+and `answer_bytes`)."""
 import json
 import sys
 from pathlib import Path
@@ -10,6 +11,7 @@ BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
 from fvb import peaks  # noqa: E402
+from fvb import spec as fspec  # noqa: E402
 
 N, W, WB = 1 << 24, 16, 4
 
@@ -19,18 +21,22 @@ def _instance(traffic: str, name: str) -> dict:
     return next(i for i in doc["instances"] if i["name"] == name)
 
 
+def _verb(spec: dict):
+    return fspec.module(BENCH / "verbs" / f"{spec['verb']}.py")
+
+
 def test_s25p_by_hand():
     # reads a0v a0x a4v a4x a5v a5x (a4v is also the predicate's): 6 words
     # of every row; writes the same 6 words of every survivor
     spec = _instance("select", "S25P")
-    assert peaks.query_bytes(spec, WB, N, W, count=1000) == \
+    assert _verb(spec).query_bytes(spec, WB, N, W, 1000) == \
         N * 6 * 4 + 1000 * 6 * 4 == 402_677_184
 
 
 def test_s50_by_hand():
     # no projection: the whole 64 B row is read and every survivor written
     spec = _instance("select", "S50")
-    assert peaks.query_bytes(spec, WB, N, W, count=3) == \
+    assert _verb(spec).query_bytes(spec, WB, N, W, 3) == \
         N * 64 + 3 * 64 == 1_073_742_016
 
 
@@ -39,7 +45,7 @@ def test_g50_by_hand():
     # writes 1024 buckets x (key, count, sum/min/max of 3 values) = 11
     # words, and each overflow row's key and 3 values
     spec = _instance("group", "G50")
-    assert peaks.query_bytes(spec, WB, N, W, n_overflow=10) == \
+    assert _verb(spec).query_bytes(spec, WB, N, W, 10) == \
         N * 20 + 1024 * 11 * 4 + 10 * 4 * 4 == 335_589_536
 
 
@@ -52,7 +58,8 @@ def test_g50_by_hand():
     ("group", "G50", 10, 10 * 4 * 4),
 ])
 def test_answer_floor_by_hand(traffic, name, count, want):
-    assert peaks.answer_bytes(_instance(traffic, name), WB, W, count) == want
+    spec = _instance(traffic, name)
+    assert _verb(spec).answer_bytes(spec, WB, W, count) == want
 
 
 def test_v5e_peaks_and_source():
