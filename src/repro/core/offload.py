@@ -221,6 +221,9 @@ def _merge(schema: FTable, pipeline: tuple,
                 kind="mask", mask=jnp.zeros((n_rows or 0,), bool))
         if plan.kind == "groups":
             return PipelineResult(kind="groups", groups={})
+        if plan.kind == "scalars":
+            return PipelineResult(kind="scalars", count=0, scalars={
+                "count": 0, "sums": (0,) * len(plan.agg_terms)})
         return PipelineResult(kind="rows", rows=jnp.zeros(
             (n_rows or 0, plan.response_width), jnp.float32), count=0)
     kind = partials[0].kind
@@ -272,6 +275,18 @@ def _merge(schema: FTable, pipeline: tuple,
             [p.groups for p in partials],
             partials[0].groups.get("drop_key"))
         return PipelineResult(kind="groups", groups=merged,
+                              shipped_bytes=sum(p.shipped_bytes or 0
+                                                for p in partials),
+                              read_bytes=sum(p.read_bytes for p in partials))
+    if kind == "scalars":
+        # exact: counts and sums are Python ints, added as such
+        sums = [0] * len(partials[0].scalars["sums"])
+        for p in partials:
+            for i, x in enumerate(p.scalars["sums"]):
+                sums[i] += int(x)
+        count = sum(int(p.scalars["count"]) for p in partials)
+        return PipelineResult(kind="scalars", count=count,
+                              scalars={"count": count, "sums": tuple(sums)},
                               shipped_bytes=sum(p.shipped_bytes or 0
                                                 for p in partials),
                               read_bytes=sum(p.read_bytes for p in partials))

@@ -4,7 +4,7 @@ A pipeline is an ordered list of operator descriptors, validated against the
 canonical stage order of Fig. 4:
 
     [Crypt(decrypt)] -> Project|SmartAddress -> [Select|RegexMatch]
-        -> [Distinct|GroupBy] -> [Crypt(encrypt)] -> Pack (implicit)
+        -> [Distinct|GroupBy|Aggregate] -> [Crypt(encrypt)] -> Pack (implicit)
 
 Descriptors are hashable; their tuple is the pipeline *signature* — the key
 of the compiled-executable cache in pipeline.py, which plays the role of the
@@ -14,8 +14,30 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import numpy as np
+
 # comparison ops (shared codes with kernels/ref.py)
 OPS = {"<": 1, "<=": 2, ">": 3, ">=": 4, "==": 5, "!=": 6}
+# predicate slots per column: a lower and an upper bound, or inequalities
+PRED_SLOTS = 2
+
+
+class PlanError(ValueError):
+    """A pipeline the plan cannot run exactly: refused before it runs."""
+
+
+class PredicateSlotError(PlanError):
+    """A column's predicates need more than PRED_SLOTS comparisons."""
+
+
+class AggregateTermError(PlanError):
+    """An Aggregate term the exact integer sum cannot take: a column that
+    is not i32, or a term of more than two columns."""
+
+
+class UnreadPredicateError(PlanError):
+    """A predicate or aggregate on a column that SmartAddress does not
+    read: it would be silently dropped."""
 
 
 @dataclass(frozen=True)
@@ -82,6 +104,15 @@ class GroupBy:
 
 
 @dataclass(frozen=True)
+class Aggregate:
+    """count(*) and exact integer sums over the selected rows (TPC-H Q6's
+    `sum(l_extendedprice * l_discount)`). Each term of `sums` is one
+    column, (col,), or the product of two, (col_a, col_b); terms name i32
+    columns only (|x| < 2^24), and every sum comes back as an exact int."""
+    sums: tuple[tuple[str, ...], ...] = ()
+
+
+@dataclass(frozen=True)
 class Crypt:
     """CTR-mode stream cipher on the data path (paper §5.5)."""
     key: tuple[int, int]
@@ -104,6 +135,7 @@ STAGE_ORDER = {
     JoinSmall: 2,      # joins compose with selection, before grouping
     Distinct: 3,
     GroupBy: 3,
+    Aggregate: 3,
     Pack: 5,
 }
 
@@ -126,7 +158,84 @@ def validate_pipeline(pipeline: tuple) -> tuple:
             n_reads += 1
     if n_reads > 1:
         raise ValueError("at most one Project/SmartAddress per pipeline")
+    reducers = [o for o in pipeline if isinstance(o, (Distinct, GroupBy,
+                                                      Aggregate))]
+    aggs = [o for o in reducers if isinstance(o, Aggregate)]
+    if aggs:
+        if len(reducers) > 1:
+            raise PlanError("an Aggregate takes no other Distinct, GroupBy "
+                            "or Aggregate in its pipeline")
+        if any(isinstance(o, (RegexMatch, JoinSmall)) or (
+                isinstance(o, Crypt) and o.when == "post") for o in pipeline):
+            raise PlanError("an Aggregate composes with Crypt(pre), "
+                            "Project/SmartAddress and Select only")
+        for term in aggs[0].sums:
+            if not 1 <= len(term) <= 2:
+                raise AggregateTermError(
+                    f"Aggregate term {term!r}: one column or the product "
+                    "of two")
+    smart = [o for o in pipeline if isinstance(o, SmartAddress)]
+    if smart:
+        read = set(smart[0].cols)
+        used = [p.col for o in pipeline if isinstance(o, Select)
+                for p in o.predicates]
+        used += [c for o in aggs for term in o.sums for c in term]
+        unread = sorted(set(used) - read)
+        if unread:
+            raise UnreadPredicateError(
+                f"SmartAddress{tuple(smart[0].cols)} does not read "
+                f"{unread}, which the pipeline filters or sums on")
     return pipeline
+
+
+def _tighter(a: tuple, b: tuple, lower: bool) -> tuple:
+    """The tighter of two bounds (op, f32 value) on one side: a NaN bound
+    holds nowhere, so it wins; at equal values the strict op wins."""
+    if np.isnan(a[1]):
+        return a
+    if np.isnan(b[1]):
+        return b
+    if a[1] != b[1]:
+        return a if (a[1] > b[1]) == lower else b
+    return a if a[0] in ("<", ">") else b
+
+
+def column_slots(predicates) -> dict[str, list[tuple[str, np.float32]]]:
+    """Each column's predicates as at most PRED_SLOTS comparisons (op, f32
+    value) whose AND is theirs. A lone predicate keeps its slot as it is;
+    several on one column merge: the lower bounds (>, >=) into the
+    tightest, the upper bounds (<, <=) likewise, `== v` counting as
+    `>= v` and `<= v`, and each `!=` keeps a slot of its own. The values
+    are compared in f32, the precision the kernels compare in.
+    PredicateSlotError where more slots would be needed."""
+    by_col: dict[str, list] = {}
+    for p in predicates:
+        if p.op not in OPS:
+            raise ValueError(f"unknown predicate op {p.op!r}")
+        by_col.setdefault(p.col, []).append((p.op, np.float32(p.value)))
+    out = {}
+    for col, preds in by_col.items():
+        if len(preds) == 1:
+            out[col] = preds
+            continue
+        lo = hi = None
+        slots = []
+        for op, v in preds:
+            if op in (">", ">=", "=="):
+                b = (">=" if op == "==" else op, v)
+                lo = b if lo is None else _tighter(lo, b, lower=True)
+            if op in ("<", "<=", "=="):
+                b = ("<=" if op == "==" else op, v)
+                hi = b if hi is None else _tighter(hi, b, lower=False)
+            if op == "!=":
+                slots.append((op, v))
+        slots = [b for b in (lo, hi) if b is not None] + slots
+        if len(slots) > PRED_SLOTS:
+            raise PredicateSlotError(
+                f"column {col!r}: {len(preds)} predicates need "
+                f"{len(slots)} comparisons, more than {PRED_SLOTS} slots")
+        out[col] = slots
+    return out
 
 
 def signature(pipeline: tuple) -> tuple:

@@ -87,14 +87,16 @@ class PipelineResult:
     """
 
     def __init__(self, kind: str, *, rows=None, count=None, groups=None,
-                 mask=None, shipped_bytes=0, read_bytes=0, sel_ids=None,
-                 _raw: dict | None = None, _meta: dict | None = None):
-        self.kind = kind                # "rows" | "groups" | "mask"
+                 mask=None, scalars=None, shipped_bytes=0, read_bytes=0,
+                 sel_ids=None, _raw: dict | None = None,
+                 _meta: dict | None = None):
+        self.kind = kind        # "rows" | "groups" | "mask" | "scalars"
         self.read_bytes = read_bytes    # static: bytes pulled from pool DRAM
         self._rows = rows
         self._count = count
         self._groups = groups
         self._mask = mask
+        self._scalars = scalars
         self._shipped = shipped_bytes
         self._ids = sel_ids             # survivors' original row ids, or None
         self._raw = _raw                # unfinalized executable payload
@@ -126,6 +128,13 @@ class PipelineResult:
     def groups(self):
         self.finalize()
         return self._groups
+
+    @property
+    def scalars(self):
+        """An Aggregate's answer: {"count": selected rows, "sums": one
+        exact int per term}."""
+        self.finalize()
+        return self._scalars
 
     @property
     def shipped_bytes(self):
@@ -184,6 +193,13 @@ class PipelineResult:
             elif self.kind == "mask":
                 self._mask = raw["mask"]
                 self._shipped = int(raw["shipped"])
+            elif self.kind == "scalars":
+                with TraceAnnotation("fv.d2h"):
+                    limbs = np.asarray(raw["limbs"])
+                count, sums = kref.limb_totals(limbs, self._meta["n_terms"])
+                self._count = count
+                self._scalars = {"count": count, "sums": sums}
+                self._shipped = int(raw["shipped"])
             else:
                 self._finalize_groups(raw)
         if self._callbacks:
@@ -235,8 +251,7 @@ class CompiledPipeline:
 
         # --- resolve static plan (one-time, off the hot path) ---------------
         a = self._n_cols or 1
-        self.sel_ops = np.zeros((a,), np.int32)
-        self.sel_vals = np.zeros((a,), np.float32)
+        preds: list = []
         self.proj_mask = np.ones((a,), np.float32)
         self.proj_cols: list[int] | None = None
         self.smart = False
@@ -246,6 +261,7 @@ class CompiledPipeline:
         self.crypt_pre: op_ir.Crypt | None = None
         self.crypt_post: op_ir.Crypt | None = None
         self.join: op_ir.JoinSmall | None = None
+        self.agg: op_ir.Aggregate | None = None
         self.has_select = False
 
         for op in pipeline:
@@ -258,10 +274,7 @@ class CompiledPipeline:
                 self.smart = True
             elif isinstance(op, op_ir.Select):
                 self.has_select = True
-                for p in op.predicates:
-                    i = self._col(p.col)
-                    self.sel_ops[i] = op_ir.OPS[p.op]
-                    self.sel_vals[i] = p.value
+                preds.extend(op.predicates)
             elif isinstance(op, op_ir.RegexMatch):
                 self.regex_tbl = compile_regex(op.pattern)
             elif isinstance(op, op_ir.JoinSmall):
@@ -270,6 +283,8 @@ class CompiledPipeline:
                 self.group = op
             elif isinstance(op, op_ir.Distinct):
                 self.distinct = op
+            elif isinstance(op, op_ir.Aggregate):
+                self.agg = op
             elif isinstance(op, op_ir.Crypt):
                 if op.when == "pre":
                     self.crypt_pre = op
@@ -282,12 +297,37 @@ class CompiledPipeline:
                                       or self.distinct is not None):
             raise ValueError("JoinSmall composes with select/project only")
 
+        # predicate slots, (A, S): as many as the busiest column needs
+        slots = op_ir.column_slots(preds)
+        n_slots = max([1] + [len(v) for v in slots.values()])
+        self.sel_ops = np.zeros((a, n_slots), np.int32)
+        self.sel_vals = np.zeros((a, n_slots), np.float32)
+        for name, col_slots in slots.items():
+            i = self._col(name)
+            for k, (code, value) in enumerate(col_slots):
+                self.sel_ops[i, k] = op_ir.OPS[code]
+                self.sel_vals[i, k] = value
+
+        # aggregate terms: exact only over i32 columns (|x| < 2^24)
+        self.agg_terms: tuple = ()
+        if self.agg is not None:
+            for term in self.agg.sums:
+                for c in term:
+                    dtype = schema.columns[self._col(c)].dtype
+                    if dtype != "i32":
+                        raise op_ir.AggregateTermError(
+                            f"Aggregate term {term!r}: column {c!r} is "
+                            f"{dtype}, not i32: its sum would not be exact")
+            self.agg_terms = tuple(tuple(self._col(c) for c in term)
+                                   for term in self.agg.sums)
+
         # the kernel lowering holds a word table column-major, (A, n)
         self._cm = not self.interpret and not self._str_width
 
         self.kind = ("mask" if self.regex_tbl is not None else
                      "groups" if (self.group is not None
-                                  or self.distinct is not None) else "rows")
+                                  or self.distinct is not None) else
+                     "scalars" if self.agg is not None else "rows")
 
         # --- the fused executables (shape-specialized lazily by jit) --------
         # Bound methods on purpose: every attribute the entries read is
@@ -493,6 +533,8 @@ class CompiledPipeline:
         meta = {"cm": self._cm, "sealed": self.crypt_post is not None}
         if self.kind == "groups":
             meta["drop_key"] = _DROP_KEY
+        if self.kind == "scalars":
+            meta["n_terms"] = len(self.agg_terms)
         return PipelineResult(self.kind, read_bytes=read_bytes,
                               _raw=payload, _meta=meta)
 
@@ -628,10 +670,25 @@ class CompiledPipeline:
             eff_sel_ops = self.sel_ops[np.asarray(self.proj_cols)]
             eff_sel_vals = self.sel_vals[np.asarray(self.proj_cols)]
             eff_proj = np.ones((len(self.proj_cols),), np.float32)
+            terms = tuple(tuple(self.proj_cols.index(c) for c in term)
+                          for term in self.agg_terms)
         else:
             eff_sel_ops = self.sel_ops
             eff_sel_vals = self.sel_vals
             eff_proj = self.proj_mask
+            terms = self.agg_terms
+
+        # -- scalar aggregate: count and exact sums, nothing packed ----------
+        if self.agg is not None:
+            if xla:
+                limbs = kops.select_aggregate_xla(
+                    work, eff_sel_ops, eff_sel_vals, terms, valid)
+            else:
+                limbs = kops.select_aggregate_cols(
+                    work, eff_sel_ops, eff_sel_vals, terms, n_valid)
+            # the count and each sum, 8 bytes each
+            shipped = np.int32((1 + len(terms)) * 8)
+            return {"limbs": limbs, "shipped": shipped}
 
         # -- small-table join: matched build values + a hit column,
         # expressed as extra predicate/projection columns so the fused
@@ -651,12 +708,12 @@ class CompiledPipeline:
             work = jnp.concatenate(
                 [work, joined, jnp.expand_dims(hit.astype(jnp.float32), ax)],
                 axis=ax)
-            eff_sel_ops = np.concatenate(
-                [eff_sel_ops, np.zeros(nb, np.int32),
-                 np.asarray([op_ir.OPS["=="]], np.int32)])
-            eff_sel_vals = np.concatenate(
-                [eff_sel_vals, np.zeros(nb, np.float32),
-                 np.asarray([1.0], np.float32)])
+            hit_ops = np.zeros((nb + 1, eff_sel_ops.shape[1]), np.int32)
+            hit_ops[nb, 0] = op_ir.OPS["=="]
+            hit_vals = np.zeros(hit_ops.shape, np.float32)
+            hit_vals[nb, 0] = 1.0
+            eff_sel_ops = np.concatenate([eff_sel_ops, hit_ops])
+            eff_sel_vals = np.concatenate([eff_sel_vals, hit_vals])
             eff_proj = np.concatenate(
                 [eff_proj, np.ones(nb, np.float32),
                  np.zeros(1, np.float32)])      # keep build cols, drop hit
@@ -681,9 +738,10 @@ class CompiledPipeline:
                 [work, jnp.expand_dims(row_ids.astype(jnp.float32), ax)],
                 axis=ax)
             eff_sel_ops = np.concatenate(
-                [eff_sel_ops, np.zeros(1, np.int32)])
+                [eff_sel_ops, np.zeros((1, eff_sel_ops.shape[1]), np.int32)])
             eff_sel_vals = np.concatenate(
-                [eff_sel_vals, np.zeros(1, np.float32)])
+                [eff_sel_vals,
+                 np.zeros((1, eff_sel_vals.shape[1]), np.float32)])
             eff_proj = np.concatenate([eff_proj, np.ones(1, np.float32)])
 
         # -- selection + projection + packing (fused) -------------------------

@@ -15,6 +15,7 @@ import numpy as np
 from repro.kernels import (ctr_crypt as _ctr, decode_attention as _dec,
                            dfa_match as _dfa, hash_group as _hg,
                            hash_join as _hj, ref,
+                           select_aggregate as _sa,
                            select_project as _sp)
 
 
@@ -38,7 +39,8 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int, value=0):
 # ---------------------------------------------------------------------------
 def select_project(table, sel_ops, sel_vals, proj_mask, *,
                    block_rows: int = 256, interpret: bool | None = None):
-    """table (N, A) f32; sel_ops (A,) i32; sel_vals/proj_mask (A,) f32.
+    """table (N, A) f32; sel_ops/sel_vals (A,) or (A, S) predicate slots,
+    i32 / f32; proj_mask (A,) f32.
 
     Returns (packed (N, A) f32 globally compacted, count scalar i32).
     """
@@ -65,10 +67,7 @@ def select_project_cols(table_t, sel_ops, sel_vals, proj_mask, n_valid=None,
     if n == 0:
         return jnp.zeros((a, 0), jnp.float32), jnp.int32(0)
     t = _pad_to(_pad_to(table_t.astype(jnp.float32), 0, 8), 1, block_rows)
-    # padded columns must not affect the predicate: pad ops with OP_SKIP
-    ops2 = _pad_to(jnp.asarray(sel_ops, jnp.int32)[:, None], 0, 8,
-                   value=ref.OP_SKIP)
-    vals2 = _pad_to(jnp.asarray(sel_vals, jnp.float32)[:, None], 0, 8)
+    ops2, vals2 = _slots(sel_ops, sel_vals, a)
     proj2 = _pad_to(jnp.asarray(proj_mask, jnp.float32)[:, None], 0, 8)
     limit = (jnp.int32(n) if n_valid is None
              else jnp.minimum(jnp.asarray(n_valid, jnp.int32), n))
@@ -77,6 +76,48 @@ def select_project_cols(table_t, sel_ops, sel_vals, proj_mask, n_valid=None,
                                        block_rows=block_rows,
                                        interpret=interpret)
     return packed[:a, :n], total
+
+
+def _slots(sel_ops, sel_vals, a: int):
+    """Predicate slots as (A, S) blocks padded to the 8-sublane tile:
+    padded columns must not affect the predicate, so their ops are
+    OP_SKIP."""
+    ops = _pad_to(jnp.asarray(sel_ops, jnp.int32).reshape(a, -1), 0, 8,
+                  value=ref.OP_SKIP)
+    vals = _pad_to(jnp.asarray(sel_vals, jnp.float32).reshape(a, -1), 0, 8)
+    return ops, vals
+
+
+def select_aggregate_cols(table_t, sel_ops, sel_vals, terms, n_valid=None,
+                          *, block_rows: int = _sa.DEFAULT_BLOCK_ROWS,
+                          interpret: bool | None = None):
+    """Count and exact integer sums of the selected rows, column-major:
+    table_t (A, N) f32; sel_ops/sel_vals (A,) or (A, S); terms a tuple of
+    one- or two-column index tuples. Rows at or past `n_valid` (optional
+    traced scalar) are never selected.
+
+    Returns limbs (1, 1 + 5 T) int32: the kernel's per-lane accumulators
+    summed over the lanes, exactly (each lane's digit is below 2^12, its
+    count and top digit below the blocks it saw); `ref.limb_totals` makes
+    them ints. The kernel and this sum run under the device scope
+    `fv.agg`."""
+    if interpret is None:
+        interpret = _interpret_default()
+    a, n = table_t.shape
+    terms = tuple(tuple(int(c) for c in term) for term in terms)
+    width = _sa.n_limb_rows(len(terms))
+    if n == 0:
+        return jnp.zeros((1, width), jnp.int32)
+    with jax.named_scope("fv.agg"):
+        t = _pad_to(_pad_to(table_t.astype(jnp.float32), 0, 8), 1,
+                    block_rows)
+        ops2, vals2 = _slots(sel_ops, sel_vals, a)
+        limit = (jnp.int32(n) if n_valid is None
+                 else jnp.minimum(jnp.asarray(n_valid, jnp.int32), n))
+        acc = _sa.select_aggregate(t, ops2, vals2, limit.reshape(1, 1),
+                                   terms=terms, block_rows=block_rows,
+                                   interpret=interpret)
+        return jnp.sum(acc[:width], axis=1)[None, :]
 
 
 # ---------------------------------------------------------------------------
@@ -343,6 +384,40 @@ def select_project_xla(table, sel_ops, sel_vals, proj_mask, valid=None):
     order = jnp.argsort(~mask, stable=True)
     packed = jnp.where(mask[order][:, None], projected[order], 0)
     return packed, jnp.sum(mask.astype(jnp.int32))
+
+
+AGG_CHUNK_ROWS = 1 << 18     # digit sums of a chunk stay below 2^30
+
+
+def select_aggregate_xla(table, sel_ops, sel_vals, terms, valid=None):
+    """`select_aggregate_cols` over (N, A) rows in plain XLA: Select's
+    lowering (`select_project_xla`) over the referenced columns, the
+    terms' first, then each survivor's terms as base-2^12 digits
+    (`ref.product_digits`), summed in int32 over chunks of AGG_CHUNK_ROWS
+    rows, where no digit sum reaches 2^31. Returns limbs (P, 1 + 5 T),
+    one row per chunk, for `ref.limb_totals`: the same totals as the
+    kernel's."""
+    n, a = table.shape
+    ops = np.asarray(sel_ops).reshape(a, -1)
+    vals = np.asarray(sel_vals).reshape(a, -1)
+    cols = list(dict.fromkeys(
+        [c for term in terms for c in term]
+        + [c for c in range(a) if (ops[c] != ref.OP_SKIP).any()]))
+    packed, count = select_project_xla(
+        jnp.take(table, np.asarray(cols, np.int32), axis=1), ops[cols],
+        vals[cols], np.ones(len(cols), np.float32), valid)
+    live = jnp.arange(n) < count
+    parts = [live.astype(jnp.int32)]
+    for term in terms:
+        x = jnp.where(live, packed[:, cols.index(term[0])].astype(jnp.int32),
+                      0)
+        y = (packed[:, cols.index(term[1])].astype(jnp.int32)
+             if len(term) == 2 else jnp.ones((n,), jnp.int32))
+        parts.extend(ref.product_digits(x, y))
+    digits = jnp.stack(parts, axis=1)                       # (N, W)
+    chunk = max(1, min(AGG_CHUNK_ROWS, n))
+    digits = _pad_to(digits, 0, chunk)
+    return jnp.sum(digits.reshape(-1, chunk, digits.shape[1]), axis=1)
 
 
 def hash_join_xla(probe_keys, build_keys, build_vals):

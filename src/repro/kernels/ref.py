@@ -18,29 +18,40 @@ KEY_SENTINEL = np.iinfo(np.int32).min  # "empty bucket" marker (hash_group)
 # ---------------------------------------------------------------------------
 # select_project
 # ---------------------------------------------------------------------------
+_CMPS = ((OP_LT, jnp.less), (OP_LE, jnp.less_equal),
+         (OP_GT, jnp.greater), (OP_GE, jnp.greater_equal),
+         (OP_EQ, jnp.equal), (OP_NE, jnp.not_equal))
+
+
 def eval_predicate(table: jnp.ndarray, sel_ops: jnp.ndarray,
-                   sel_vals: jnp.ndarray, *, col_axis: int = 1) -> jnp.ndarray:
-    """AND-of-per-column-comparisons predicate.
+                   sel_vals: jnp.ndarray, *, col_axis: int = 1,
+                   keepdims: bool = False) -> jnp.ndarray:
+    """AND of every column's predicate slots.
 
     table: (N, A) float32/int32 columns — or (A, N) with col_axis=0.
-    sel_ops: (A,) int32 op codes (OP_SKIP disables the column).
-    sel_vals: (A,) same dtype as table, comparison constants.
-    Returns (N,) bool mask.
+    sel_ops: (A,) or (A, S) int32 op codes, one per column and slot
+    (OP_SKIP holds everywhere). sel_vals: the same shape, the constants.
+    Returns (N,) bool mask ((1, N) or (N, 1) with keepdims).
+
+    The one predicate code path: the select and aggregate kernels call it
+    on their blocks, the XLA lowering and the group path on the whole
+    table. It selects between f32 values only, since Mosaic cannot select
+    between i1 vectors.
     """
-    shape = [1, 1]
-    shape[col_axis] = -1
-    col = table
-    val = sel_vals.reshape(shape)
-    ops = sel_ops.reshape(shape)
-    per_col = jnp.where(
-        ops == OP_LT, col < val,
-        jnp.where(ops == OP_LE, col <= val,
-                  jnp.where(ops == OP_GT, col > val,
-                            jnp.where(ops == OP_GE, col >= val,
-                                      jnp.where(ops == OP_EQ, col == val,
-                                                jnp.where(ops == OP_NE, col != val,
-                                                          True))))))
-    return jnp.all(per_col, axis=col_axis)
+    a = table.shape[col_axis]
+    ops = jnp.asarray(sel_ops).reshape(a, -1)
+    vals = jnp.asarray(sel_vals).reshape(a, -1)
+    f32 = jnp.float32
+    ok = None
+    for s in range(ops.shape[1]):
+        op, val = ops[:, s:s + 1], vals[:, s:s + 1]        # (A, 1)
+        if col_axis == 1:
+            op, val = op.T, val.T
+        hit = jnp.ones(table.shape, f32)
+        for code, cmp in _CMPS:
+            hit = jnp.where(op == code, cmp(table, val).astype(f32), hit)
+        ok = hit if ok is None else jnp.minimum(ok, hit)
+    return jnp.min(ok, axis=col_axis, keepdims=keepdims) > 0.5
 
 
 def select_project(table: jnp.ndarray, sel_ops: jnp.ndarray,
@@ -58,6 +69,73 @@ def select_project(table: jnp.ndarray, sel_ops: jnp.ndarray,
     order = jnp.argsort(~mask, stable=True)
     packed = jnp.where(mask[order][:, None], projected[order], 0)
     return packed, jnp.sum(mask.astype(jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# select_aggregate (count and exact integer sums of the selected rows)
+# ---------------------------------------------------------------------------
+LIMB_BITS = 12
+LIMB_MASK = (1 << LIMB_BITS) - 1
+N_DIGITS = 5        # base-2^12 digits of a product of two |x| < 2^24
+
+
+def product_digits(a, b):
+    """a * b as N_DIGITS base-2^12 digits, for int32 arrays with |a|, |b|
+    <= 2^24: a * b = sum(d[j] << 12 j), d[0..3] in [0, 4096) and d[4] in
+    [-4096, 4096). Every intermediate stays below 2^26 in magnitude, so
+    int32 holds it; the kernels and the XLA lowering share this."""
+    a0, a1 = a & LIMB_MASK, a >> LIMB_BITS
+    b0, b1 = b & LIMB_MASK, b >> LIMB_BITS
+    t = a0 * b0
+    d0 = t & LIMB_MASK
+    t = (t >> LIMB_BITS) + a1 * b0 + a0 * b1
+    d1 = t & LIMB_MASK
+    t = (t >> LIMB_BITS) + a1 * b1
+    d2 = t & LIMB_MASK
+    t = t >> LIMB_BITS
+    return d0, d1, d2, t & LIMB_MASK, t >> LIMB_BITS
+
+
+def limb_totals(limbs: np.ndarray, n_terms: int) -> tuple[int, tuple]:
+    """(count, sums) of partial limbs (P, 1 + N_DIGITS * n_terms) int32:
+    column 0 counts rows, column 1 + N_DIGITS t + j holds digit j (weight
+    2^(12 j)) of term t. Partials add in int64 (P * 2^31 < 2^63), digits
+    combine as Python ints: exact at any magnitude."""
+    tot = np.asarray(limbs, np.int64).reshape(-1, 1 + N_DIGITS * n_terms
+                                              ).sum(axis=0)
+    sums = tuple(sum(int(tot[1 + N_DIGITS * t + j]) << (LIMB_BITS * j)
+                     for j in range(N_DIGITS)) for t in range(n_terms))
+    return int(tot[0]), sums
+
+
+def aggregate_exact(table, sel_ops, sel_vals, terms, valid=None):
+    """The plain reference: (count, sums) over the rows the predicate
+    selects, in numpy alone: each slot's comparison in f32, each term's
+    sum in int64, with no blocks or limbs.
+
+    table: (N, A) words; sel_ops/sel_vals: (A,) or (A, S) slots; terms:
+    tuples of one or two column indices, the columns integer-valued;
+    valid: optional (N,) bool row mask."""
+    table = np.asarray(table, np.float32)
+    a = table.shape[1]
+    ops = np.asarray(sel_ops).reshape(a, -1)
+    vals = np.asarray(sel_vals, np.float32).reshape(a, -1)
+    cmps = {OP_LT: np.less, OP_LE: np.less_equal, OP_GT: np.greater,
+            OP_GE: np.greater_equal, OP_EQ: np.equal, OP_NE: np.not_equal}
+    mask = np.ones(table.shape[0], bool)
+    for (c, s), code in np.ndenumerate(ops):
+        if code != OP_SKIP:
+            mask &= cmps[int(code)](table[:, c], vals[c, s])
+    if valid is not None:
+        mask &= np.asarray(valid, bool)
+    rows = table[mask]
+    sums = []
+    for term in terms:
+        prod = np.ones(rows.shape[0], np.int64)
+        for c in term:
+            prod = prod * rows[:, c].astype(np.int64)
+        sums.append(int(prod.sum()))
+    return int(mask.sum()), tuple(sums)
 
 
 # ---------------------------------------------------------------------------

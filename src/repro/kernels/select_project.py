@@ -37,31 +37,22 @@ from repro.kernels import ref
 
 DEFAULT_BLOCK_ROWS = 256
 _RING = 8      # output blocks in flight: a slot's DMA is awaited on reuse
-_CMPS = ((ref.OP_LT, jnp.less), (ref.OP_LE, jnp.less_equal),
-         (ref.OP_GT, jnp.greater), (ref.OP_GE, jnp.greater_equal),
-         (ref.OP_EQ, jnp.equal), (ref.OP_NE, jnp.not_equal))
 
 
 def _kernel(limit_ref, table_ref, ops_ref, vals_ref, proj_ref, zeros_ref,
             out_ref, count_ref, win_ref, ring_ref, state_ref, sems):
     del zeros_ref                          # aliased to out_ref: the zero tail
     cols = table_ref[...]                                    # (C, R) f32
-    ops = ops_ref[...]                                       # (C, 1) i32
-    vals = vals_ref[...]                                     # (C, 1) f32
     proj = proj_ref[...]                                     # (C, 1) f32
     f32 = jnp.float32
     r = cols.shape[1]
     i = pl.program_id(0)
 
-    # --- predicate (VPU) ---------------------------------------------------
-    # 1.0 where a column's predicate holds (OP_SKIP always holds); selects
-    # over f32, since Mosaic cannot select between i1 vectors
-    ok = jnp.ones(cols.shape, f32)
-    for code, cmp in _CMPS:
-        ok = jnp.where(ops == code, cmp(cols, vals).astype(f32), ok)
+    # --- predicate (VPU): every column's slots (C, S), OP_SKIP holds --------
     # rows at or past `limit` (tail padding, masked n_valid rows) never match
     row = i * r + jax.lax.broadcasted_iota(jnp.int32, (1, r), 1)
-    mask = (jnp.min(ok, axis=0, keepdims=True) > 0.5) & (row < limit_ref[0, 0])
+    mask = ref.eval_predicate(cols, ops_ref[...], vals_ref[...], col_axis=0,
+                              keepdims=True) & (row < limit_ref[0, 0])
     mask_f = mask.astype(f32)                                # (1, R)
 
     # --- projection (annotate columns, paper's projection_flags) -----------
@@ -156,7 +147,8 @@ def select_project(table_t: jnp.ndarray, sel_ops: jnp.ndarray,
     """Globally packed survivors + their count, column-major.
 
     table_t: (C, N) f32, C % 8 == 0, N % block_rows == 0 (wrapper pads).
-    sel_ops: (C, 1) int32 opcodes; sel_vals/proj_mask: (C, 1) f32.
+    sel_ops: (C, S) int32 opcodes, S predicate slots a column;
+    sel_vals: (C, S) f32; proj_mask: (C, 1) f32.
     limit: (1, 1) int32 — rows >= limit never survive.
     Returns: packed (C, N) f32 — survivors in row order in lanes
     [0, count), zeros past them — and count, a scalar i32.
@@ -174,13 +166,14 @@ def _select_project(table_t, sel_ops, sel_vals, proj_mask, limit, *,
     c, n = table_t.shape
     assert n % block_rows == 0 and c % 8 == 0, (c, n)
     col = pl.BlockSpec((c, 1), lambda i: (0, 0))
+    slots = pl.BlockSpec((c, sel_ops.shape[1]), lambda i: (0, 0))
     packed, count = pl.pallas_call(
         _kernel,
         grid=(n // block_rows,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((c, block_rows), lambda i: (0, i)),
-            col, col, col,
+            slots, slots, col,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[

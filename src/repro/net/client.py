@@ -93,7 +93,7 @@ class RemotePending:
             res = PipelineResult(
                 payload["kind"], rows=rows,
                 count=payload.get("count"), groups=payload.get("groups"),
-                mask=payload.get("mask"),
+                mask=payload.get("mask"), scalars=payload.get("scalars"),
                 shipped_bytes=int(payload.get("shipped", 0)),
                 read_bytes=int(payload.get("read", 0)),
                 sel_ids=payload.get("sel_ids"))

@@ -67,8 +67,10 @@ class ServerLifecycleError(fv.FarviewError):
 def _result_payload(res) -> dict:
     """Flatten a FINALIZED PipelineResult into wire values. The client
     rebuilds an already-finalized result from these — `offload._merge`
-    reads only kind/count/rows/sel_ids/mask/groups/shipped/read, so the
-    rebuilt partial merges byte-identically to an in-process one."""
+    reads only kind/count/rows/sel_ids/mask/groups/scalars/shipped/read,
+    so the rebuilt partial merges byte-identically to an in-process one.
+    An Aggregate's sums are Python ints: the wire carries one past 64
+    bits as a length-prefixed signed big int, so none can overflow."""
     out = {"kind": res.kind, "count": res._count,
            "shipped": int(res._shipped or 0),
            "read": int(res.read_bytes or 0)}
@@ -89,6 +91,10 @@ def _result_payload(res) -> dict:
             k: (np.asarray(v) if isinstance(v, (np.ndarray, list))
                 or hasattr(v, "__array__") else v)
             for k, v in res._groups.items()}
+    if res._scalars is not None:
+        out["scalars"] = {"count": int(res._scalars["count"]),
+                          "sums": tuple(int(x) for x in
+                                        res._scalars["sums"])}
     return out
 
 

@@ -117,7 +117,8 @@ DATACLASS_REGISTRY = {
     cls.__name__: cls
     for cls in (op_ir.Project, op_ir.SmartAddress, op_ir.Predicate,
                 op_ir.Select, op_ir.RegexMatch, op_ir.JoinSmall,
-                op_ir.Distinct, op_ir.GroupBy, op_ir.Crypt, op_ir.Pack,
+                op_ir.Distinct, op_ir.GroupBy, op_ir.Aggregate,
+                op_ir.Crypt, op_ir.Pack,
                 Column, FTable)
 }
 

@@ -240,6 +240,38 @@ class TestWireParity:
         assert_rows_identical(net_run(trio, pipe, words)[0],
                               solo_run(pipe, words))
 
+    @pytest.mark.parametrize("partitioner", ["range", "hash"])
+    def test_q6_scalar_aggregate(self, trio, partitioner):
+        """TPC-H Q6's shape over three servers: range predicates and an
+        exact sum of a product, the partials' Python ints added by the
+        client; sums pass 2^53, where an f64 stops being exact."""
+        rng = np.random.default_rng(6)
+        cols = tuple(Column(n, "i32") for n in ("ship", "disc", "qty",
+                                                "price"))
+        ft = FTable("li", cols, n_rows=N)
+        words = np.stack([rng.integers(0, 2557, N), rng.integers(0, 11, N),
+                          rng.integers(1, 51, N),
+                          rng.integers(2**23, 2**24, N)],
+                         axis=1).astype(np.float32)
+        words[:200, 1] = 2**24 - 1
+        pipe = (op.Select((op.Predicate("ship", ">=", 731.0),
+                           op.Predicate("ship", "<", 1827.0),
+                           op.Predicate("disc", ">=", 5.0),
+                           op.Predicate("disc", "<=", 2.0**24),
+                           op.Predicate("qty", "<", 40.0))),
+                op.Aggregate((("price", "disc"), ("qty",))))
+        keys = words[:, 2].astype(np.int32) if partitioner == "hash" else None
+        res, *_ = net_run(trio, pipe, words, partitioner=partitioner,
+                          keys=keys, ft=ft)
+        m = ((words[:, 0] >= 731) & (words[:, 0] < 1827)
+             & (words[:, 1] >= 5) & (words[:, 2] < 40))
+        rev = sum(int(p) * int(d) for p, d in words[m][:, [3, 1]])
+        assert rev > 2**53
+        assert res.kind == "scalars"
+        assert res.scalars == {"count": int(m.sum()), "sums": (
+            rev, int(words[m, 2].astype(np.int64).sum()))}
+        assert res.scalars == solo_run(pipe, words, ft=ft).scalars
+
     def test_group_aggregate(self, trio, data):
         pipe = (op.GroupBy("c0", ("c1", "c2"), n_buckets=128),)
         words = schema().encode(data)

@@ -1,6 +1,7 @@
 """Compile rehearsals for the TPU: the main path's Pallas kernels and the
 fused request executables, compiled by the TPU compiler for a described
-(not attached) v5e chip at benchmark widths (8-word rows).
+(not attached) v5e chip at benchmark widths (8-word rows; the aggregate
+kernel at TPC-H lineitem's 32).
 
 Nothing runs; each test asserts the compiler accepted the program and
 that it holds a Mosaic kernel (`tpu_custom_call`), so a kernel the chip's
@@ -67,6 +68,15 @@ KERNELS = {
         lambda t, o, v, p: kops.select_project_cols(t, o, v, p),
         ((W + 1, N), jnp.float32), ((W + 1,), jnp.int32),
         ((W + 1,), jnp.float32), ((W + 1,), jnp.float32)),
+    "select_project_ranges": (
+        lambda t, o, v, p: kops.select_project_cols(t, o, v, p),
+        ((W + 1, N), jnp.float32), ((W + 1, 2), jnp.int32),
+        ((W + 1, 2), jnp.float32), ((W + 1,), jnp.float32)),
+    "select_aggregate": (
+        lambda t, o, v: kops.select_aggregate_cols(t, o, v,
+                                                   ((1, 2), (3,))),
+        ((4 * W, N), jnp.float32), ((4 * W, 2), jnp.int32),
+        ((4 * W, 2), jnp.float32)),
     "group_aggregate": (
         lambda k, v: kops.group_aggregate_cols(k, v, n_buckets=1024),
         ((N,), jnp.int32), ((3, N), jnp.float32)),
@@ -97,6 +107,9 @@ PIPES = {
     "smart_address": (op.SmartAddress(("c4", "c5")), SEL),
     "pre_decrypt": (op.Crypt(key=(21, 42), nonce=99, when="pre"), SEL),
     "group": (SEL, op.GroupBy("c0", ("c1", "c2"), n_buckets=1024)),
+    "aggregate": (op.Select((op.Predicate("c4", ">=", 0.1),
+                             op.Predicate("c4", "<", 0.6))),
+                  op.Aggregate((("c0", "c0"), ("c0",)))),
 }
 
 

@@ -7,8 +7,9 @@ Host spans: `fv.d2h` and `fv.layout` in `PipelineResult.finalize`,
 in the client's frame read, `fv.attach` where the client rebuilds a
 result. Device scopes: `fv.bucket_sort` (the group kernel's bucket sort
 and ownership, and the stream put in bucket order), `fv.ovf_pack` (the
-group's overflow compaction); a select carries none, its compaction
-being all in the select kernel. Each is checked where it lands: the
+group's overflow compaction), `fv.agg` (the scalar aggregate's kernel
+and the sum of its lanes); a select carries none, its compaction being
+all in the select kernel. Each is checked where it lands: the
 spans in a profiler trace, the scopes in the compiled program's op
 names, which a device trace carries as each op's name path.
 """
@@ -29,6 +30,9 @@ N = 600
 COLS = tuple(Column(f"c{i}", "i32" if i == 0 else "f32") for i in range(8))
 SEL = op.Select((op.Predicate("c2", "<", 0.3),))
 GROUP = (SEL, op.GroupBy("c0", ("c1", "c3"), n_buckets=64))
+AGG = (op.Select((op.Predicate("c2", ">=", -0.5),
+                  op.Predicate("c2", "<", 0.3))),
+       op.Aggregate((("c0", "c0"), ("c0",))))
 
 
 def _rows(seed=0):
@@ -61,7 +65,8 @@ def _spans(tmp_path, fn) -> dict:
     ((op.Project(("c1", "c4")), SEL), False, ()),
     (GROUP, False, ("fv.bucket_sort", "fv.ovf_pack")),
     (GROUP, True, ("fv.ovf_pack",)),
-], ids=["select-kernels", "group-kernels", "group-xla"])
+    (AGG, False, ("fv.agg",)),
+], ids=["select-kernels", "group-kernels", "group-xla", "aggregate-kernels"])
 def test_device_scopes_name_the_compiled_ops(pipeline, interpret, scopes):
     pipe = CompiledPipeline(FTable("t", COLS, n_rows=N), pipeline,
                             interpret=interpret)
@@ -71,7 +76,8 @@ def test_device_scopes_name_the_compiled_ops(pipeline, interpret, scopes):
         assert f"/{scope}/" in text, scope
     # no path carries a scope it does not name (a select none: its
     # compaction is all in the select kernel)
-    for scope in {"fv.stitch", "fv.bucket_sort", "fv.ovf_pack"} - set(scopes):
+    for scope in ({"fv.stitch", "fv.bucket_sort", "fv.ovf_pack", "fv.agg"}
+                  - set(scopes)):
         assert f"/{scope}/" not in text, scope
 
 
